@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 
+import jax
 import numpy as np
 
 from benchmarks.common import time_fn, csv_row
@@ -40,6 +41,7 @@ from repro.core import planner as P
 from repro.core import registry as R
 from repro.core.engines import LocalEngine, DistributedEngine
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _build(n_vertices: int, symmetric: bool) -> G.GraphCOO:
@@ -217,7 +219,8 @@ def emit_calibration(path, samples, out=print) -> P.CalibrationProfile:
                                     * np.median(ratios))
         kwargs["superstep_edge_bytes"] = fitted
     profile = P.CalibrationProfile(
-        algo_time_scale=scales, source="benchmarks/algo_suite.py", **kwargs)
+        algo_time_scale=scales, source="benchmarks/algo_suite.py",
+        platform=jax.default_backend(), **kwargs)
     profile.to_json(path)
     out(csv_row("algo_suite/calibration_written", 0.0,
                 f"path={path} algorithms={len(scales)}"))
@@ -237,4 +240,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,6 +20,7 @@ from repro.core import graph as G
 from repro.core import planner as P
 from repro.core.engines import LocalEngine, DistributedEngine
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(out=print):
@@ -70,4 +71,5 @@ def run(out=print):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -22,6 +22,7 @@ from repro.core.algorithms.two_hop import (two_hop_pairs,
                                            two_hop_count_upper_bound)
 from repro.core.algorithms.legacy import legacy_multi_account
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(out=print):
@@ -65,4 +66,5 @@ def run(out=print):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -10,6 +10,7 @@ from repro.core import graph as G
 from repro.core.algorithms.connected_components import connected_components
 from repro.core.algorithms.legacy import legacy_connected_users
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(out=print):
@@ -51,4 +52,5 @@ def run(out=print):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -45,6 +45,7 @@ from repro.core import registry as R
 from repro.core.query import GraphQuery
 from repro.core.service import GraphAnalyticsService
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 SIZES = (50_000, 200_000)
 DELTA_FRACTIONS = (0.001, 0.01, 0.1)
@@ -272,4 +273,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

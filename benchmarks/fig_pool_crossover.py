@@ -37,6 +37,7 @@ from repro.core import pools as PL
 from repro.core.query import GraphQuery
 from repro.core.service import GraphAnalyticsService
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 SIZES = (2_000, 20_000, 100_000)
 #: cross-pool byte rates swept: 1 MB/s .. 100 GB/s in decade steps
@@ -140,4 +141,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -24,6 +24,7 @@ from repro.kernels.ell_combine.ops import ell_spmv, ell_spmv_ref
 from repro.kernels.pregel_superstep import (fused_superstep,
                                             fused_superstep_ref)
 from repro.models.layers import attn_chunked, attn_ref
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(out=print):
@@ -113,4 +114,5 @@ def run(out=print):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -41,6 +41,7 @@ from repro.core.algorithms import traversal
 from repro.core.engines import LocalEngine
 from repro.data import synthetic as S
 from repro.kernels.pregel_superstep import fused_superstep_ref
+from repro.utils.compile_cache import enable_compile_cache
 
 INTERPRET_NOTE = ("interpret (CPU fallback — not indicative of TPU "
                   "perf)")
@@ -258,4 +259,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -10,6 +10,7 @@ from benchmarks.common import csv_row
 from repro.configs.base import SHAPES, get_config
 from repro.utils.analytic import cost_cell
 from repro.utils import roofline as RL
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "dryrun")
 
@@ -94,4 +95,5 @@ def run(out=print):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     render()

@@ -5,6 +5,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks import (fig5_engine_crossover, fig6_multi_account,
@@ -26,4 +28,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

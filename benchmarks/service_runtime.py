@@ -34,6 +34,7 @@ from repro.core import planner as P
 from repro.core.query import GraphQuery
 from repro.core.service import GraphAnalyticsService
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 WORKER_SWEEP = (1, 2, 4, 8)
 N_VERTICES = 2_000
@@ -200,4 +201,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ from __future__ import annotations
 from benchmarks.common import csv_row
 from repro.data.etl import max_adjacent_nodes_sweep
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(out=print):
@@ -27,4 +28,5 @@ def run(out=print):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

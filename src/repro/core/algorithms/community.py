@@ -123,7 +123,7 @@ def label_propagation(
     G.require_symmetric(g, "label_propagation")
     V = g.n_vertices
     if sharded is None:
-        sharded = partition(g, n_data, n_model)
+        sharded = partition(g, n_data, n_model, mesh=mesh)
     init = jnp.arange(sharded.n_pad, dtype=jnp.int32)
     spec = _lpa_spec(n_channels, float(self_weight))
     labels, iters = run_pregel(spec, sharded, init, max_iters, mesh=mesh)

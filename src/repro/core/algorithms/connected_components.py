@@ -73,7 +73,7 @@ def connected_components(
     """
     V = g.n_vertices
     if sharded is None:
-        sharded = partition(g, n_data, n_model)
+        sharded = partition(g, n_data, n_model, mesh=mesh)
     v_local = sharded.v_local
     replicated = sharded.n_model == 1
     spec = _CC_SPEC_JUMP if (accelerated and replicated) else _CC_SPEC

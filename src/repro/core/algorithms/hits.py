@@ -107,7 +107,7 @@ def hits(
     ``run_pregel`` call, i.e. one XLA program."""
     V = g.n_vertices
     if sharded is None:
-        sharded = partition(role_graph(g), n_data, n_model)
+        sharded = partition(role_graph(g), n_data, n_model, mesh=mesh)
     init = jnp.zeros(sharded.n_pad, jnp.float32).at[: 2 * V].set(
         1.0 / np.sqrt(max(V, 1)))
     state, iters = run_pregel(_hits_spec(V, float(tol)), sharded, init,
@@ -149,7 +149,7 @@ def _engine_run(eng, max_iters, tol):
     key = "hits/sharded"
     if key not in eng.cache:
         eng.cache[key] = partition(role_graph(eng.coo), eng.n_data,
-                                   eng.n_model)
+                                   eng.n_model, mesh=eng.mesh)
     return hits(eng.coo, max_iters=max_iters, tol=tol, mesh=eng.mesh,
                 sharded=eng.cache[key])
 
@@ -173,7 +173,7 @@ def _warm_start(eng, params, seed):
     key = "hits/sharded"
     if key not in eng.cache:
         eng.cache[key] = partition(role_graph(eng.coo), eng.n_data,
-                                   eng.n_model)
+                                   eng.n_model, mesh=eng.mesh)
     sharded = eng.cache[key]
     base = np.float32(1.0 / np.sqrt(max(V, 1)))
     init = np.zeros(sharded.n_pad, dtype=np.float32)

@@ -28,7 +28,7 @@ from repro.core.pregel import PregelSpec, run_pregel
 
 
 def _normalize_and_partition(
-    g: G.GraphCOO, n_data: int, n_model: int
+    g: G.GraphCOO, n_data: int, n_model: int, mesh=None
 ) -> tuple[ShardedCOO, jax.Array]:
     """Fold 1/outdeg into edge weights; return sharded edges + dangling mask."""
     outdeg = G.out_degrees(g)
@@ -37,7 +37,7 @@ def _normalize_and_partition(
     src_c = jnp.clip(g.src, 0, g.n_vertices - 1)
     w_norm = g.w * inv[src_c]
     g_norm = G.GraphCOO(g.src, g.dst, w_norm, g.n_vertices, g.n_edges)
-    return partition(g_norm, n_data, n_model), dangling
+    return partition(g_norm, n_data, n_model, mesh=mesh), dangling
 
 
 def pagerank(
@@ -60,7 +60,8 @@ def pagerank(
     warm start changes iterations, never the converged ranks beyond
     ``tol``."""
     if sharded is None:
-        sharded, dangling = _normalize_and_partition(g, n_data, n_model)
+        sharded, dangling = _normalize_and_partition(g, n_data, n_model,
+                                                     mesh)
     V = g.n_vertices
     v_local = sharded.v_local
     n_model_eff = sharded.n_model
@@ -106,7 +107,7 @@ def _engine_run(eng, alpha, tol, max_iters):
     key = "pagerank/normalized"
     if key not in eng.cache:
         eng.cache[key] = _normalize_and_partition(
-            eng.coo, eng.n_data, eng.n_model)
+            eng.coo, eng.n_data, eng.n_model, eng.mesh)
     sharded, dangling = eng.cache[key]
     return pagerank(eng.coo, alpha=alpha, tol=tol, max_iters=max_iters,
                     mesh=eng.mesh, sharded=sharded, dangling=dangling)
@@ -134,7 +135,7 @@ def _warm_start(eng, params, seed):
     key = "pagerank/normalized"
     if key not in eng.cache:
         eng.cache[key] = _normalize_and_partition(
-            eng.coo, eng.n_data, eng.n_model)
+            eng.coo, eng.n_data, eng.n_model, eng.mesh)
     sharded, dangling = eng.cache[key]
     if sharded.n_model > 1:
         init = jnp.zeros(sharded.n_model * sharded.v_local,

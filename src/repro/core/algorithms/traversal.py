@@ -73,7 +73,7 @@ def _run_relaxation(spec, g: G.GraphCOO, sources, max_iters, mesh,
         # bound costs nothing on small-diameter graphs
         max_iters = g.n_vertices
     if sharded is None:
-        sharded = partition(g, n_data, n_model)
+        sharded = partition(g, n_data, n_model, mesh=mesh)
     init = _init_distances(sources, g.n_vertices, sharded.n_pad)
     dist, iters = run_pregel(spec, sharded, init, max_iters, mesh=mesh)
     return dist[: g.n_vertices], iters

@@ -110,7 +110,7 @@ def triangle_count(
     V = g.n_vertices
     W = _n_words(V)
     if sharded is None:
-        sharded = partition(g, n_data, n_model)
+        sharded = partition(g, n_data, n_model, mesh=mesh)
     # own-bit bitset rows; the trailing word accumulates the pair counts
     init = np.zeros((sharded.n_pad, W + 1), dtype=np.uint32)
     ids = np.arange(V, dtype=np.int64)
@@ -192,7 +192,7 @@ def k_core(
     if max_iters is None:
         max_iters = V
     if sharded is None:
-        sharded = partition(g, n_data, n_model)
+        sharded = partition(g, n_data, n_model, mesh=mesh)
     init = jnp.ones(sharded.n_pad, jnp.float32)
     alive, iters = run_pregel(_kcore_spec(int(k)), sharded, init,
                               max_iters, mesh=mesh)

@@ -43,7 +43,6 @@ from repro.core.pregel import (
     run_pregel_frontier,
     run_pregel_fused,
 )
-from repro.kernels.ell_combine import ops as ell_ops
 
 # Byte budget for the *uncapped* ELL layouts the fused/frontier superstep
 # variants execute over (every edge retained — no MaxAdjacentNodes cap,
@@ -106,6 +105,11 @@ class Engine:
         # default) means profiling is off and the hot path pays a single
         # attribute read.
         self._profile_sink: Optional[list] = None
+        # The superstep variant the last ``run_superstep`` executed (a
+        # requested fused/frontier run falls back to dense when its
+        # preconditions fail); run()/run_batch() report it in
+        # ``meta['variant']``.  Written and read under _exec_lock.
+        self._ran_variant: Optional[str] = None
         # Measurements are read by the *planner* path (submit-time
         # current_stats) while a worker may hold _exec_lock for a long
         # batch run — a separate lock keeps submit latency flat.
@@ -119,7 +123,7 @@ class Engine:
         with self._exec_lock:
             if self._sharded is None:
                 self._sharded = partition(self.coo, self.n_data,
-                                          self.n_model)
+                                          self.n_model, mesh=self.mesh)
             return self._sharded
 
     @property
@@ -242,7 +246,9 @@ class Engine:
         loop; both fall back to dense when ``superstep_supported`` says
         no, so a planner-forced variant never errors and the variants
         contract (identical results everywhere) holds unconditionally.
-        ``'auto'`` prefers frontier, then fused, then dense.
+        ``'auto'`` prefers frontier, then fused, then dense.  The variant
+        that actually ran is what the result's ``meta['variant']``
+        reports.
 
         ``init_active`` (optional ``bool [V]``) seeds the frontier
         variant's first active set — the incremental-maintenance seam.
@@ -259,14 +265,13 @@ class Engine:
         """
         v = variant or "dense"
         if v == "auto":
-            if self.superstep_supported(spec, "frontier"):
-                v = "frontier"
-            elif self.superstep_supported(spec, "fused"):
-                v = "fused"
-            else:
-                v = "dense"
+            v = next((c for c in ("frontier", "fused")
+                      if self.superstep_supported(spec, c)), "dense")
+        elif v != "dense" and not self.superstep_supported(spec, v):
+            v = "dense"
+        self._ran_variant = v
         sink = self._profile_sink
-        if v == "fused" and self.superstep_supported(spec, "fused"):
+        if v == "fused":
             V = self.coo.n_vertices
             state, iters = run_pregel_fused(
                 spec, self.superstep_ell("in"), init_state[:V], max_iters,
@@ -276,7 +281,7 @@ class Engine:
                     "fused", spec, init_state, iters, max_iters,
                     slots_per_iter=int(self.superstep_ell("in").nbr.size)))
             return state, iters
-        if v == "frontier" and self.superstep_supported(spec, "frontier"):
+        if v == "frontier":
             V = self.coo.n_vertices
             active = None if init_active is None else init_active[:V]
             if sink is None:
@@ -433,6 +438,7 @@ class Engine:
         sink = None
         with self._exec_lock, self._device_scope():
             self.n_runs += 1
+            self._ran_variant = None
             if profile:
                 self._profile_sink = []
             try:
@@ -460,6 +466,7 @@ class Engine:
                     else:
                         value, iters = self._invoke(
                             defn.runner_for(variant), defn, p)
+                ran = self._ran_variant
             finally:
                 if profile:
                     sink, self._profile_sink = self._profile_sink, None
@@ -469,7 +476,8 @@ class Engine:
         meta = {}
         if not count_fast:
             if variant is not None:
-                meta["variant"] = variant
+                # a superstep runner reports what ran; others what was asked
+                meta["variant"] = ran or variant
             if mode is not None:
                 meta["mode"] = mode
         if sink:
@@ -505,11 +513,13 @@ class Engine:
         sink = None
         with self._exec_lock, self._device_scope():
             self.n_runs += 1
+            self._ran_variant = None
             if profile:
                 self._profile_sink = []
             try:
                 R.apply_fault(defn.name)  # one fused execution, one fault
                 values, iters, fused_meta = defn.batch_runner(self, ps)
+                ran = self._ran_variant
             finally:
                 if profile:
                     sink, self._profile_sink = self._profile_sink, None
@@ -524,6 +534,8 @@ class Engine:
                 value = defn.count(value)
             meta = {"fused": {"batch_size": len(ps), "index": i,
                               **(fused_meta or {})}}
+            if ran is not None:
+                meta["variant"] = ran
             if sink:
                 # one fused execution -> the same shared counters on
                 # every member's result (stripped, like 'fused', from
@@ -602,9 +614,10 @@ class LocalEngine(Engine):
     """Single-device in-memory engine (Neo4j analogue).
 
     Holds the graph in exact COO (+ the degree-capped ELL for motif/
-    similarity queries).  Algorithm loops run through the Pallas
-    ``ell_combine`` kernel path when shapes are TPU-tileable, else the
-    jnp reference — same numerics.
+    similarity queries).  ``use_pallas`` routes the fused superstep and
+    the ELL-intersect triangle path through their Pallas kernels instead
+    of the jnp references — same numerics.  Off by default: the served
+    path runs XLA programs only.
     """
 
     name = "local"
@@ -614,7 +627,6 @@ class LocalEngine(Engine):
         super().__init__(coo, mesh=None, n_data=1, n_model=1,
                          max_degree=max_degree)
         self.use_pallas = use_pallas
-        self._spmv = ell_ops.ell_spmv if use_pallas else ell_ops.ell_spmv_ref
 
     def _clone(self) -> "LocalEngine":
         return LocalEngine(self.coo, max_degree=self.max_degree,

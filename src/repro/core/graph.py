@@ -340,21 +340,35 @@ def build_coo(
     symmetrize: bool = False,
     dedup: bool = True,
 ) -> GraphCOO:
-    """Sort edges by destination, optionally symmetrize/dedup, pad."""
+    """Sort edges by destination, optionally symmetrize/dedup, pad.
+
+    Duplicate (src, dst) pairs keep their first occurrence's weight;
+    within a destination, edges are ordered by source."""
     src = np.asarray(src, dtype=np.int32)
     dst = np.asarray(dst, dtype=np.int32)
-    if w is None:
+    unit_w = w is None
+    if unit_w:
         w = np.ones_like(src, dtype=np.float32)
     w = np.asarray(w, dtype=np.float32)
     if symmetrize:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
         w = np.concatenate([w, w])
     if dedup and src.size:
-        key = src.astype(np.int64) * np.int64(n_vertices + 1) + dst.astype(np.int64)
-        _, keep = np.unique(key, return_index=True)
-        src, dst, w = src[keep], dst[keep], w[keep]
-    order = np.argsort(dst, kind="stable")
-    src, dst, w = src[order], dst[order], w[order]
+        # destination-major keys: unique's sorted order is the final edge
+        # order, so no second sort is needed
+        stride = np.int64(n_vertices + 1)
+        key = dst.astype(np.int64) * stride + src.astype(np.int64)
+        if unit_w:              # every weight is 1: no first-occurrence
+            key = np.unique(key)
+            src = (key % stride).astype(np.int32)
+            dst = (key // stride).astype(np.int32)
+            w = np.ones_like(src, dtype=np.float32)
+        else:
+            _, keep = np.unique(key, return_index=True)
+            src, dst, w = src[keep], dst[keep], w[keep]
+    else:
+        order = np.argsort(dst, kind="stable")
+        src, dst, w = src[order], dst[order], w[order]
     n_edges = int(src.shape[0])
     e_pad = max(pad_multiple, round_up(n_edges, pad_multiple))
     sentinel = np.int32(n_vertices)

@@ -15,7 +15,11 @@ shards so one BSP superstep is a single statically-shaped `shard_map`:
 
 Partitioning is host-side numpy (ETL territory), output arrays are laid
 out shard-major so ``PartitionSpec`` along the leading dim places each
-shard on its device without resharding.
+shard on its device without resharding.  Given the engine's mesh, the
+arrays are placed once with the same spec the superstep's ``shard_map``
+reads them with (``P('data')``, or ``P(('data', 'model'))`` for the 2-D
+layout), so no query moves edges between devices.  Without a mesh they
+land on the default device.
 """
 from __future__ import annotations
 
@@ -24,6 +28,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.graph import GraphCOO, round_up
 
@@ -71,7 +76,17 @@ def _pack_shards(groups, e_shard, sentinel):
     return src.reshape(-1), dst.reshape(-1), w.reshape(-1)
 
 
-def partition_1d(g: GraphCOO, n_data: int, pad_multiple: int = 256) -> ShardedCOO:
+def _place(arrays, mesh, spec):
+    """Device arrays: sharded over ``mesh`` by ``spec``, or on the
+    default device when there is no mesh."""
+    if mesh is None:
+        return tuple(jnp.asarray(a) for a in arrays)
+    sharding = NamedSharding(mesh, spec)
+    return tuple(jax.device_put(a, sharding) for a in arrays)
+
+
+def partition_1d(g: GraphCOO, n_data: int, pad_multiple: int = 256,
+                 mesh=None) -> ShardedCOO:
     """Round-robin edge split over the data axis (vertex state replicated)."""
     src = np.asarray(g.src)[: g.n_edges]
     dst = np.asarray(g.dst)[: g.n_edges]
@@ -81,16 +96,18 @@ def partition_1d(g: GraphCOO, n_data: int, pad_multiple: int = 256) -> ShardedCO
     for d in range(n_data):
         sel = slice(d, None, n_data)  # strided → balanced across dst ranges
         groups.append((src[sel], dst[sel], w[sel]))
-    s, dd, ww = _pack_shards(groups, e_shard, np.int32(g.n_vertices))
+    s, dd, ww = _place(_pack_shards(groups, e_shard, np.int32(g.n_vertices)),
+                       mesh, P("data"))
     return ShardedCOO(
-        src=jnp.asarray(s), dst=jnp.asarray(dd), w=jnp.asarray(ww),
+        src=s, dst=dd, w=ww,
         n_vertices=g.n_vertices, n_edges=g.n_edges,
         n_data=n_data, n_model=1, e_shard=e_shard, v_local=g.n_vertices,
     )
 
 
 def partition_2d(
-    g: GraphCOO, n_data: int, n_model: int, pad_multiple: int = 256
+    g: GraphCOO, n_data: int, n_model: int, pad_multiple: int = 256,
+    mesh=None,
 ) -> ShardedCOO:
     """Vertex-cut: model axis owns dst ranges, data axis splits within."""
     src = np.asarray(g.src)[: g.n_edges]
@@ -111,9 +128,10 @@ def partition_2d(
         groups.append(per_d)
     e_shard = max(pad_multiple, round_up(max_block, pad_multiple))
     flat = [groups[m][d] for d in range(n_data) for m in range(n_model)]
-    s, dd, ww = _pack_shards(flat, e_shard, np.int32(g.n_vertices))
+    s, dd, ww = _place(_pack_shards(flat, e_shard, np.int32(g.n_vertices)),
+                       mesh, P(("data", "model")))
     return ShardedCOO(
-        src=jnp.asarray(s), dst=jnp.asarray(dd), w=jnp.asarray(ww),
+        src=s, dst=dd, w=ww,
         n_vertices=g.n_vertices, n_edges=g.n_edges,
         n_data=n_data, n_model=n_model, e_shard=e_shard, v_local=v_local,
     )

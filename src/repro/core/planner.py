@@ -27,7 +27,9 @@ Two feedback loops replace analytic guesses with measurements:
   ``benchmarks/algo_suite.py --emit-calibration`` writes from wall-clock
   measurements and :func:`load_calibration` applies process-wide —
   including the service tier thresholds (interactive-vs-batch
-  classification and the admission budget).
+  classification and the admission budget).  A profile records the
+  platform it was fitted on, and the checked-in reference profile is
+  only the default on that platform (:func:`default_calibration`).
 """
 from __future__ import annotations
 
@@ -41,6 +43,7 @@ from repro.core import registry
 # TPU v5e-flavored constants (per chip) — the analytic defaults that seed
 # CalibrationProfile; estimates read the *active profile*, so
 # load_calibration overrides these without touching module globals.
+# They come from the v5e data sheet, not from a fit on any device.
 HBM_BW = 819e9            # B/s
 LINK_BW = 50e9            # B/s per ICI link
 HOST_EGRESS_BW = 4e9      # B/s device->host for result materialization
@@ -126,6 +129,9 @@ class CalibrationProfile:
     superstep_edge_bytes: Mapping[str, float] = dataclasses.field(
         default_factory=dict)
     source: str = "analytic-defaults"
+    # JAX backend the profile was fitted on ('cpu', 'tpu', ...); None for
+    # the analytic defaults, which no device measured.
+    platform: Optional[str] = None
 
     def scale(self, algorithm: str) -> float:
         return float(self.algo_time_scale.get(algorithm, 1.0))
@@ -165,11 +171,13 @@ class CalibrationProfile:
 
 
 #: The checked-in calibration residue: a reference profile emitted by
-#: ``benchmarks/algo_suite.py --emit-calibration`` on a real box.  It is
-#: auto-loaded at import so production callers start from measured
-#: constants; tests pin the analytic defaults (``set_calibration(None)``
-#: in ``tests/conftest.py``) because the fitted values are
-#: box-specific.
+#: ``benchmarks/algo_suite.py --emit-calibration`` on the platform its
+#: ``platform`` field names.  It becomes the active profile on first use
+#: in a process whose JAX backend is that platform; on any other device
+#: its constants would be wrong by orders of magnitude, so the analytic
+#: defaults hold there.  Tests pin the analytic defaults
+#: (``set_calibration(None)`` in ``tests/conftest.py``) because the
+#: fitted values are box-specific.
 _REFERENCE_PROFILE = os.path.join(os.path.dirname(__file__),
                                   "calibration", "reference_profile.json")
 
@@ -178,24 +186,29 @@ def reference_profile_path() -> str:
     return _REFERENCE_PROFILE
 
 
-def _load_reference() -> Optional["CalibrationProfile"]:
+def default_calibration() -> CalibrationProfile:
+    """The profile a process starts from: the reference profile when it
+    was fitted on the running JAX backend, else the analytic defaults —
+    also when the reference cannot be read."""
+    import jax
     try:
-        return CalibrationProfile.from_json(_REFERENCE_PROFILE)
+        ref = CalibrationProfile.from_json(_REFERENCE_PROFILE)
     except (OSError, ValueError, TypeError):
-        return None
+        return CalibrationProfile()
+    return (ref if ref.platform == jax.default_backend()
+            else CalibrationProfile())
 
 
-_REFERENCE = _load_reference()
-#: True when the checked-in reference profile parsed and became the
-#: import-time default (the calibration-residue contract).
-AUTO_LOADED_REFERENCE = _REFERENCE is not None
-
-_ACTIVE_PROFILE = _REFERENCE if _REFERENCE is not None \
-    else CalibrationProfile()
+# None until first use: resolving the default asks JAX for its backend,
+# which must not happen while this module is imported.
+_ACTIVE_PROFILE: Optional[CalibrationProfile] = None
 _PROFILE_GENERATION = 0    # bumped on every swap; plan caches key on it
 
 
 def active_calibration() -> CalibrationProfile:
+    global _ACTIVE_PROFILE
+    if _ACTIVE_PROFILE is None:
+        _ACTIVE_PROFILE = default_calibration()
     return _ACTIVE_PROFILE
 
 
@@ -221,9 +234,9 @@ def load_calibration(path) -> CalibrationProfile:
 
 
 def load_reference_calibration() -> CalibrationProfile:
-    """(Re-)install the checked-in reference profile — the explicit form
-    of the import-time auto-load (tests that pinned the analytic
-    defaults use this to opt back in)."""
+    """(Re-)install the checked-in reference profile on any platform —
+    the explicit form of the first-use default (tests that pinned the
+    analytic defaults use this to opt back in)."""
     return load_calibration(_REFERENCE_PROFILE)
 
 
@@ -265,7 +278,7 @@ def superstep_specs(algorithm: str, *, output_rows: int, iterations: int,
     multiplier.  Dense comes first so cost ties keep the oracle path
     (``choose_plan`` prefers earlier specs on ties).
     """
-    pr = _ACTIVE_PROFILE
+    pr = active_calibration()
     names = ("dense", "fused", "frontier") if frontier \
         else ("dense", "fused")
     return tuple(
@@ -361,7 +374,7 @@ def estimate_local_cost(g: GraphStats, q: QuerySpec,
                         profile: Optional[CalibrationProfile] = None) -> float:
     """One device streams the edge set from HBM each superstep, then
     egresses the output to the host once."""
-    pr = profile or _ACTIVE_PROFILE
+    pr = profile or active_calibration()
     if g.bytes_coo + q.state_bytes_per_vertex * g.n_vertices \
             > pr.local_mem_budget:
         return float("inf")
@@ -379,7 +392,7 @@ def estimate_dist_cost(g: GraphStats, q: QuerySpec, n_chips: int,
     """Each chip streams E/P edges; every superstep pays a launch/sync and
     a ring all-reduce of the vertex aggregate; output egress parallelizes
     over hosts."""
-    pr = profile or _ACTIVE_PROFILE
+    pr = profile or active_calibration()
     n_chips = max(n_chips, 1)
     touched = (g.bytes_coo * q.edge_bytes_factor / n_chips
                + q.state_bytes_per_vertex * g.n_vertices) * q.iterations
@@ -423,7 +436,7 @@ def full_traffic_cost(g: GraphStats, q: QuerySpec,
     """The cold run's edge/state traffic seconds — the *variable* term
     of :func:`estimate_local_cost`, without the fixed dispatch and
     output-egress costs a seeded run pays identically."""
-    pr = profile or _ACTIVE_PROFILE
+    pr = profile or active_calibration()
     touched = (g.bytes_coo * q.edge_bytes_factor
                + q.state_bytes_per_vertex * g.n_vertices) * q.iterations
     return pr.scale(q.algorithm) * touched / pr.hbm_bw
@@ -443,7 +456,7 @@ def estimate_incremental_cost(g: GraphStats, q: QuerySpec, delta,
     comparison, amortized over every query the version serves).
     ``delta`` needs ``n_touched`` — :class:`repro.core.graph.
     GraphDelta` or anything shaped like it."""
-    pr = profile or _ACTIVE_PROFILE
+    pr = profile or active_calibration()
     V = max(g.n_vertices, 1)
     frac = min(1.0, INCR_WAVEFRONT_EXPANSION * delta.n_touched / V)
     iters = max(1.0, q.iterations * frac)

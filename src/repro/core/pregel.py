@@ -33,7 +33,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.partition import ShardedCOO
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 
@@ -130,10 +129,11 @@ class SuperstepVariant:
     Registered in an AlgorithmDef's ``variants`` mapping next to the
     dense spec (the triangle_count bitset-vs-intersect idiom), so the
     cost model picks dense vs fused vs frontier per graph.  Engines
-    dispatch it through ``Engine.run_superstep`` — which silently falls
-    back to the dense path when the strategy's preconditions don't hold
-    on that engine, keeping the variants contract (identical results on
-    every variant) unconditional.
+    dispatch it through ``Engine.run_superstep`` — which falls back to
+    the dense path when the strategy's preconditions don't hold on that
+    engine, keeping the variants contract (identical results on every
+    variant) unconditional; the result's ``meta['variant']`` names the
+    variant that ran.
     """
 
     spec: PregelSpec
@@ -448,7 +448,7 @@ def run_pregel(
     if fn is None:
         edge_spec = P((axis_data, axis_model)) if sharded else P(axis_data)
         state_spec = P(axis_model) if sharded else P()
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(edge_spec, edge_spec, edge_spec, state_spec),
@@ -456,8 +456,7 @@ def run_pregel(
             check_vma=False,
         ))
         _jit_cache_put(key, fn)
-    with mesh:
-        return fn(sg.src, sg.dst, sg.w, init_state)
+    return fn(sg.src, sg.dst, sg.w, init_state)
 
 
 def _check_superstep_spec(spec: PregelSpec, what: str) -> None:

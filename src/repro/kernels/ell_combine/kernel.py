@@ -14,8 +14,11 @@ TPU mapping
   source ``x`` VMEM-resident (vertex states are O(V) floats; for the
   sharded engine V is the per-shard vertex range, which fits VMEM for
   v_local <= ~1M — the ops wrapper enforces the budget).
-* The gather ``x[nbr]`` is a dynamic-gather over the VMEM-resident
-  vector — lane-aligned because K is padded to 128 and R to 8 sublanes.
+* The gather ``x[nbr]`` is a ``jnp.take`` of a ``(R, K)`` index tile
+  from the VMEM-resident vector.  The chip's compiler refuses it
+  (Mosaic: "Only 2D gather is supported" — it lowers only a same-shape
+  ``take_along_axis``), so this kernel runs in interpret mode only and
+  no engine calls it.
 * The reduce is a VPU row-reduction; no MXU involvement (SpMV is
   bandwidth-bound, the roofline term we optimize is HBM streaming of the
   (R, K) tiles, which this layout makes perfectly sequential).

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from repro.kernels.ell_intersect.kernel import ell_intersect_pallas
 from repro.kernels.ell_intersect.ref import ell_intersect_ref
 
-_LANE = 128
 _SUBLANE = 8
 MAX_KERNEL_K = 2048      # beyond this the (R, K) tiles outgrow VMEM; ref
 
@@ -30,24 +29,25 @@ def _round_up(n, m):
 
 
 def ell_intersect(a, b, sentinel: int, block_edges: int = 256):
-    """Pallas path (interpret on CPU) for one pair of row tiles.
+    """Pallas path (interpret on CPU) for one pair of ``(E, K)`` row
+    tiles; the kernel reads them transposed (edges on lanes).
 
     Falls back to the reference when K exceeds the VMEM tile budget the
     kernel design assumes."""
     e, k = a.shape
     if k > MAX_KERNEL_K:
         return ell_intersect_ref(a, b, sentinel=sentinel)
-    ep = _round_up(max(e, _SUBLANE), block_edges)
-    kp = _round_up(k, _LANE)
+    ep = _round_up(max(e, 1), block_edges)
+    kp = _round_up(k, _SUBLANE)
     if (ep, kp) != (e, k):
         a = jnp.pad(a, ((0, ep - e), (0, kp - k)),
                     constant_values=sentinel)
         b = jnp.pad(b, ((0, ep - e), (0, kp - k)),
                     constant_values=sentinel)
-    y = ell_intersect_pallas(a, b, sentinel=sentinel, k_valid=k,
+    y = ell_intersect_pallas(a.T, b.T, sentinel=sentinel, k_valid=k,
                              block_edges=block_edges,
                              interpret=_on_cpu())
-    return y[:e]
+    return y[0, :e]
 
 
 def ell_intersect_rows_ref(a, b, sentinel: int, block_edges: int = 256):

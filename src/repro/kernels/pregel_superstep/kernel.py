@@ -18,6 +18,11 @@ TPU mapping
   a ``(R, K)`` tile of ``nbr``/``mask``/``w`` from HBM and keeps the
   whole gather source ``x`` VMEM-resident (the ops wrapper enforces a
   byte budget and falls back to the jnp reference beyond it).
+* The gather ``x[nbr]`` is a ``jnp.take`` of a ``(R, K)`` index tile
+  from the VMEM-resident vector.  The chip's compiler refuses it
+  (Mosaic: "Only 2D gather is supported" — it lowers only a same-shape
+  ``take_along_axis``), so this kernel runs in interpret mode only and
+  is off the served path; ``LocalEngine`` keeps ``use_pallas`` False.
 * ``message`` is inlined into the kernel body — it must be elementwise
   jnp code (the ``PregelSpec.elementwise_message`` contract), so it
   compiles to VPU ops over the gathered tile.

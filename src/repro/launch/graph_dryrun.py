@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax import lax
 
-from repro.utils.compat import shard_map
-
 from repro.launch.mesh import make_production_mesh, n_chips
 from repro.utils import roofline as RL
 from repro.core.graph import round_up
@@ -92,9 +90,9 @@ def lower_pagerank_grid(mesh, n_vertices: int, n_edges: int,
                                  sharding=NamedSharding(mesh, edge_spec))
     x_sds = jax.ShapeDtypeStruct((n_data * v_loc_d,), sdt,
                                  sharding=NamedSharding(mesh, x_spec))
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(edge_spec, edge_spec, edge_spec, x_spec),
-                   out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(edge_spec, edge_spec, edge_spec, x_spec),
+                       out_specs=x_spec, check_vma=False)
     with mesh:
         lowered = jax.jit(fn).lower(src_sds, src_sds, w_sds, x_sds)
         t0 = time.time()
@@ -158,9 +156,9 @@ def lower_pagerank(mesh, n_vertices: int, n_edges: int, n_iters: int = 20,
         (n_model * v_local,), jnp.float32,
         sharding=NamedSharding(mesh, state_spec))
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(edge_spec, edge_spec, edge_spec, state_spec),
-                   out_specs=state_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(edge_spec, edge_spec, edge_spec, state_spec),
+                       out_specs=state_spec, check_vma=False)
     with mesh:
         lowered = jax.jit(fn).lower(src_sds, src_sds, w_sds, x_sds)
         t0 = time.time()

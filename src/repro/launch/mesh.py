@@ -8,18 +8,21 @@ from __future__ import annotations
 
 import jax
 
-from repro.utils import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 v5e chips) or 2x16x16 two-pod (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    return compat.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """A mesh with Auto axes: shardings propagate through ``jit`` the
+    way the engines' ``shard_map`` programs expect (``jax.make_mesh``
+    defaults to Explicit axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_local_mesh(n_data: int = 1, n_model: int = 1):
@@ -27,7 +30,7 @@ def make_local_mesh(n_data: int = 1, n_model: int = 1):
     n = len(jax.devices())
     n_data = min(n_data, n)
     n_model = max(1, min(n_model, n // n_data))
-    return compat.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

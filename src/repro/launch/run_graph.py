@@ -16,6 +16,7 @@ import numpy as np
 from repro.core import graph as G
 from repro.core.query import GraphQuery, GraphPlatform
 from repro.data import synthetic as S
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--n-data", type=int, default=1,
                     help="edge shards for the distributed engine")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = args.vertices
     if args.job == "two-hop":

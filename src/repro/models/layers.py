@@ -213,7 +213,6 @@ def attn_ring(q, k, v, *, mesh, axis: str = "model", batch_axes=("data",),
     logically global.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.utils.compat import shard_map, axis_size
 
     b, s, hq, dh = q.shape
     hkv = k.shape[2]
@@ -226,7 +225,7 @@ def attn_ring(q, k, v, *, mesh, axis: str = "model", batch_axes=("data",),
     needs_pos = causal or not (isinstance(window, int) and window == 0)
 
     def body(q_l, k_l, v_l):
-        M = axis_size(axis)
+        M = lax.axis_size(axis)
         bl, s_loc = q_l.shape[0], q_l.shape[1]
         if needs_pos:
             m_idx = lax.axis_index(axis)
@@ -252,15 +251,15 @@ def attn_ring(q, k, v, *, mesh, axis: str = "model", batch_axes=("data",),
 
         ((_, _), (m_f, l_f, acc)), _ = lax.scan(
             stage, ((k_l, v_l), (m0, l0, a0)),
-            jnp.arange(axis_size(axis)))
+            jnp.arange(lax.axis_size(axis)))
         out = acc / jnp.where(l_f > 0, l_f, 1.0)
         # [B,Hkv,G,Sq,Dh] -> [B,Sq,Hq,Dh]
         out = out.transpose(0, 3, 1, 2, 4).reshape(bl, s_loc, hq, dh)
         return out.astype(q_l.dtype)
 
     spec = P(bspec, axis, None, None)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -494,12 +494,32 @@ def test_trivial_pool_metrics_mirror_aggregate_depths(graph):
 # Reference calibration roundtrip (the ROADMAP calibration residue)
 # ---------------------------------------------------------------------------
 
-def test_reference_profile_is_checked_in_and_autoloads():
-    assert P.AUTO_LOADED_REFERENCE, \
-        "reference_profile.json missing or unparseable at import"
+def test_reference_profile_is_checked_in_and_autoloads(monkeypatch):
+    import jax
     ref = P.CalibrationProfile.from_json(P.reference_profile_path())
     assert ref.source != "analytic-defaults"
     assert ref.algo_time_scale            # fitted, non-empty
+    # the default is the reference only on the platform it was fitted on
+    assert ref.platform == "cpu"
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert P.default_calibration() == ref
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    other = P.default_calibration()
+    assert other == P.CalibrationProfile()
+    assert other.source == "analytic-defaults"
+
+
+def test_default_profile_resolves_on_first_use_not_at_import():
+    import subprocess
+    import sys
+    code = ("import jax, repro.core.planner as P\n"
+            "assert P._ACTIVE_PROFILE is None\n"
+            "assert P.active_calibration().platform == "
+            "jax.default_backend()\n"
+            "print('LAZY_OK')")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert "LAZY_OK" in r.stdout, r.stderr[-2000:]
 
 
 def test_load_reference_calibration_bumps_generation_and_applies():

@@ -119,7 +119,6 @@ GRID_SCRIPT = textwrap.dedent("""
     import numpy as np, jax, jax.numpy as jnp
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.utils.compat import shard_map
     from repro.launch.mesh import make_mesh
     from repro.core.graph import round_up
 
@@ -167,9 +166,9 @@ GRID_SCRIPT = textwrap.dedent("""
         new_d = lax.dynamic_slice_in_dim(full, d_idx * v_d, v_d)
         return new_d
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(('data', 'model')),) * 3 + (P('data'),),
-                   out_specs=P('data'), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(('data', 'model')),) * 3 + (P('data'),),
+                       out_specs=P('data'), check_vma=False)
     with mesh:
         got = jax.jit(fn)(jnp.asarray(Sf), jnp.asarray(Df), jnp.asarray(Wf),
                           jnp.asarray(x0))
