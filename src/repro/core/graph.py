@@ -346,8 +346,7 @@ def build_coo(
     within a destination, edges are ordered by source."""
     src = np.asarray(src, dtype=np.int32)
     dst = np.asarray(dst, dtype=np.int32)
-    unit_w = w is None
-    if unit_w:
+    if w is None:
         w = np.ones_like(src, dtype=np.float32)
     w = np.asarray(w, dtype=np.float32)
     if symmetrize:
@@ -356,16 +355,10 @@ def build_coo(
     if dedup and src.size:
         # destination-major keys: unique's sorted order is the final edge
         # order, so no second sort is needed
-        stride = np.int64(n_vertices + 1)
-        key = dst.astype(np.int64) * stride + src.astype(np.int64)
-        if unit_w:              # every weight is 1: no first-occurrence
-            key = np.unique(key)
-            src = (key % stride).astype(np.int32)
-            dst = (key // stride).astype(np.int32)
-            w = np.ones_like(src, dtype=np.float32)
-        else:
-            _, keep = np.unique(key, return_index=True)
-            src, dst, w = src[keep], dst[keep], w[keep]
+        key = (dst.astype(np.int64) * np.int64(n_vertices + 1)
+               + src.astype(np.int64))
+        _, keep = np.unique(key, return_index=True)
+        src, dst, w = src[keep], dst[keep], w[keep]
     else:
         order = np.argsort(dst, kind="stable")
         src, dst, w = src[order], dst[order], w[order]

@@ -17,9 +17,6 @@ All generators are numpy + seeded (deterministic tests/benchmarks).
 """
 from __future__ import annotations
 
-import os
-from concurrent.futures import ThreadPoolExecutor
-
 import numpy as np
 
 
@@ -93,35 +90,17 @@ def identifier_edge_sets(n_users: int, n_sets: int = 4,
 
 def rmat_graph(scale: int, edge_factor: int = 8, seed: int = 0,
                a=0.57, b=0.19, c=0.19):
-    """Graph500-style R-MAT: 2^scale vertices, edge_factor*2^scale edges.
-
-    Each edge descends ``scale`` levels of the 2x2 quadrant split: (0, 0)
-    with probability a, (0, 1) with b, (1, 0) with c, (1, 1) with the
-    rest.  Edges are drawn in a fixed number of chunks, each from its own
-    stream of ``seed``, on a thread pool (numpy releases the GIL), so the
-    output depends only on the arguments.  Self-loops are dropped.
-    """
+    """Graph500-style R-MAT: 2^scale vertices, edge_factor*2^scale edges."""
+    rng = np.random.default_rng(seed)
     n = 1 << scale
     m = edge_factor * n
-    n_chunks = 16
-    bounds = np.linspace(0, m, n_chunks + 1).astype(np.int64)
-    streams = np.random.SeedSequence(seed).spawn(n_chunks)
-    idx_dtype = np.int32 if scale < 31 else np.int64
-    src = np.zeros(m, dtype=idx_dtype)
-    dst = np.zeros(m, dtype=idx_dtype)
-
-    def chunk(i):
-        rng = np.random.default_rng(streams[i])
-        lo, hi = bounds[i], bounds[i + 1]
-        s, d = src[lo:hi], dst[lo:hi]
-        for bit in range(scale):
-            r = rng.random(hi - lo, dtype=np.float32)
-            s |= (r >= a + b).astype(idx_dtype) << bit
-            d |= (((r >= a) & (r < a + b)) | (r >= a + b + c)).astype(
-                idx_dtype) << bit
-
-    with ThreadPoolExecutor(max_workers=min(n_chunks,
-                                            os.cpu_count() or 1)) as ex:
-        list(ex.map(chunk, range(n_chunks)))
+    src = np.zeros(m, dtype=np.int64)
+    dst = np.zeros(m, dtype=np.int64)
+    for bit in range(scale):
+        r = rng.random(m)
+        s_bit = (r >= a + b) & (r < a + b + c) | (r >= a + b + c)
+        d_bit = ((r >= a) & (r < a + b)) | (r >= a + b + c)
+        src |= s_bit.astype(np.int64) << bit
+        dst |= d_bit.astype(np.int64) << bit
     keep = src != dst
-    return src[keep].astype(np.int64), dst[keep].astype(np.int64), n
+    return src[keep], dst[keep], n

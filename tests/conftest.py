@@ -1,8 +1,10 @@
 """Shared test fixtures.
 
-The planner auto-loads the checked-in reference ``CalibrationProfile``
-(``repro/core/calibration/reference_profile.json``) at import — the
-production default.  The unit suites, however, pin their expectations
+On first use the planner resolves its default ``CalibrationProfile``:
+the checked-in reference profile
+(``repro/core/calibration/reference_profile.json``) when it was fitted
+on the running JAX backend (``cpu``), else the analytic defaults.  The
+unit suites, however, pin their expectations
 (variant crossovers, tier thresholds, admission estimates) to the
 *analytic* constants, so every test runs with calibration reset to the
 analytic defaults; the reference profile's own coverage lives in the

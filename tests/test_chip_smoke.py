@@ -76,7 +76,9 @@ def test_service_phase_rehearsal():
     lines = []
     C.service_phase(C.graph500(10, seed=0), seed=0, out=lines.append)
     text = "\n".join(lines)
-    assert "retries=0 dead_letters=0" in text
+    assert "retries=0 dead_letters=0 fused_batches=1" in text
+    assert sum("bfs[" in ln and "tier=batch" in ln and "fused_width=4" in ln
+               for ln in lines) == 4
     assert "0 labels differ" in text and "0 distances differ" in text
     assert sum("ticket(s) drained" in ln for ln in lines) == 5
 

@@ -20,7 +20,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import graph as G
 from repro.core.algorithms.connected_components import connected_components
+from repro.core.algorithms.traversal import _BFS_SPEC
 from repro.core.partition import ShardedCOO
+from repro.core.pregel import batched_spec, run_pregel
 from repro.kernels.ell_intersect.kernel import ell_intersect_pallas
 from repro.launch.mesh import make_mesh
 
@@ -84,6 +86,24 @@ def test_dense_cc_superstep_compiles_at_smoke_scale(topo):
     one_chip = SingleDeviceSharding(topo.devices[0])
     compiled = _cc_program(1, None).lower(
         *_edge_shapes(one_chip, E_SMOKE)).compile()
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_fused_bfs_superstep_compiles_at_smoke_scale(topo):
+    """The smoke's four BFS tickets fuse into one dense program over
+    [V, 4] distance state."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def program(src, dst, w, init):
+        sg = ShardedCOO(src, dst, w, n_vertices=V_SMOKE, n_edges=E_SMOKE,
+                        n_data=1, n_model=1, e_shard=E_SMOKE,
+                        v_local=V_SMOKE)
+        return run_pregel(batched_spec(_BFS_SPEC), sg, init, V_SMOKE)
+
+    init = jax.ShapeDtypeStruct((V_SMOKE, 4), jnp.float32,
+                                sharding=one_chip)
+    compiled = jax.jit(program).lower(
+        *_edge_shapes(one_chip, E_SMOKE), init).compile()
     assert _total_bytes(compiled) < HBM_BYTES
 
 
