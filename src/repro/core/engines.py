@@ -332,7 +332,6 @@ class Engine:
             "iterations": n,
             "max_iters": int(max_iters),
             "halted": n < int(max_iters),
-            "halt_step": n,
             "message_slots": int(slots_total),
             "message_bytes": int(slots_total) * int(itemsize),
         }

@@ -195,14 +195,18 @@ class Tracer:
                     "retained": len(self._traces), **self.counters}
 
     # -- lifecycle hooks (called by the service) ----------------------------
-    def on_submit(self, ticket, t_submit: float, *,
+    def on_submit(self, ticket, t_submit: float, plan_span: tuple, *,
                   admission: dict, plan_attrs: dict,
                   candidates: tuple = (),
                   original_placement: Optional[dict] = None) -> None:
         """Open a ticket's trace: root + submit(admission, plan) spans,
-        then the queue-wait span.  ``original_placement`` records the
-        pre-spill plan when the submit path re-placed the ticket."""
+        then the queue-wait span.  ``t_submit`` is when the submit call
+        began and ``plan_span`` the ``(t0, t1)`` of its planner call;
+        admission is an instant, now, when the ticket was queued.
+        ``original_placement`` records the pre-spill plan when the
+        submit path re-placed the ticket."""
         now = self.clock()
+        t_plan, t_planned = plan_span
         with self._lock:
             root = self._span("ticket", t_submit,
                               ticket_id=ticket.ticket_id,
@@ -211,12 +215,10 @@ class Tracer:
                               tier=ticket.tier, est_s=ticket.est_s)
             submit = root.child(self._sid(), "submit", t_submit)
             submit.t1 = now
-            adm = submit.child(self._sid(), "admission", t_submit,
-                               **admission)
+            adm = submit.child(self._sid(), "admission", now, **admission)
             adm.t1 = now
-            plan = submit.child(self._sid(), "plan", t_submit,
-                                **plan_attrs)
-            plan.t1 = now
+            plan = submit.child(self._sid(), "plan", t_plan, **plan_attrs)
+            plan.t1 = t_planned
             plan.attrs["candidates"] = [
                 dataclasses.asdict(c) if dataclasses.is_dataclass(c)
                 else dict(c) for c in candidates]
@@ -634,10 +636,21 @@ class PlanAccuracyMeter:
 # ---------------------------------------------------------------------------
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_]")
+# the sign of a number's exponent ("le_1e-02", "le_1e+02" bucket keys)
+_EXP_SIGN_RE = re.compile(r"(?<=\de)[+-](?=\d)")
+
+
+def _sanitize(part: str) -> str:
+    """A path component as a metric name part: an exponent's sign as
+    ``m``/``p`` (so ``le_1e-02`` and ``le_1e+02`` stay apart), any other
+    character outside ``[a-zA-Z0-9_]`` as ``_``."""
+    part = _EXP_SIGN_RE.sub(lambda m: "m" if m.group() == "-" else "p",
+                            part)
+    return _NAME_RE.sub("_", part)
 
 
 def _metric_name(prefix: str, path: tuple) -> str:
-    parts = [_NAME_RE.sub("_", str(p)) for p in (prefix,) + path]
+    parts = [_sanitize(str(p)) for p in (prefix,) + path]
     name = "_".join(p for p in parts if p)
     if name and name[0].isdigit():
         name = "_" + name

@@ -12,6 +12,19 @@ lowers to) maps onto a TPU mesh as::
                collective)
     apply    : per-vertex state update                     (VPU)
 
+Each phase of the superstep body runs under a ``jax.named_scope``, so the
+ops of the compiled program carry its name in their HLO ``op_name`` and a
+profiler trace can charge device time to it: ``pregel.gather`` (source-
+and destination-state gathers), ``pregel.combine`` (the segment reduce),
+``pregel.combine_empty`` (the count that finds vertices with no message
+under min/max), ``pregel.apply`` (global value, apply, padding mask),
+``pregel.halt`` (the halt test) and ``pregel.exchange`` (the collectives
+on a mesh); an op under nested scopes belongs to the innermost.  A scope
+is metadata only: the program and its fusions are the same without it.
+On the host, each ``run_pregel*`` call is one ``pregel.dispatch`` span
+from its jit-cache lookup to the return of the program call
+(``_dispatch``).
+
 Everything is statically shaped: padded edges carry the sentinel vertex id
 and are dropped at the segment-combine.  Convergence is decided *inside*
 the jitted loop with a global ``psum`` of per-shard change counts, so a
@@ -24,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 from collections import OrderedDict
 from typing import Callable, Optional
 
@@ -32,6 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.core import obs
 from repro.core.partition import ShardedCOO
 
 Array = jax.Array
@@ -275,15 +290,18 @@ def _local_combine(msgs, dst, n_vertices, v_local, start, op, identity):
                                         n_vertices, v_local, start, o, ident))
             c0 += width
         return jnp.concatenate(parts, axis=-1)
-    local_dst = jnp.where(dst >= n_vertices, v_local, dst - start)
-    local_dst = jnp.clip(local_dst, 0, v_local)
-    agg = _SEG[op](msgs, local_dst, num_segments=v_local + 1)[:v_local]
+    with jax.named_scope("pregel.combine"):
+        local_dst = jnp.where(dst >= n_vertices, v_local, dst - start)
+        local_dst = jnp.clip(local_dst, 0, v_local)
+        agg = _SEG[op](msgs, local_dst, num_segments=v_local + 1)[:v_local]
     if op in ("min", "max"):
         # segment_min/max give +/-inf (or int extremes) for empty segments;
         # normalize to the declared identity.
-        no_msg = _SEG["sum"](jnp.ones_like(msgs, dtype=jnp.int32),
-                             local_dst, num_segments=v_local + 1)[:v_local] == 0
-        agg = jnp.where(no_msg, jnp.asarray(identity, agg.dtype), agg)
+        with jax.named_scope("pregel.combine_empty"):
+            no_msg = _SEG["sum"](jnp.ones_like(msgs, dtype=jnp.int32),
+                                 local_dst,
+                                 num_segments=v_local + 1)[:v_local] == 0
+            agg = jnp.where(no_msg, jnp.asarray(identity, agg.dtype), agg)
     return agg
 
 
@@ -346,6 +364,28 @@ def _jit_cache_put(key, fn) -> None:
             _JIT_CACHE.popitem(last=False)
 
 
+def _dispatch(key, make: Callable, *args):
+    """Call the program cached under ``key`` (``make()`` builds it on a
+    miss) as one ``pregel.dispatch`` span: the host time from the lookup
+    to the return of the program call, a missed program's trace and
+    compile included.  The span is a profiler annotation tagged
+    ``jit_cache=hit|miss`` and, at the same two clock reads, an
+    ``obs.emit`` event carrying ``t0``/``t1`` (``time.perf_counter``)
+    and ``jit_cache`` for an installed tracer."""
+    with jax.profiler.TraceAnnotation("pregel.dispatch") as span:
+        t0 = time.perf_counter()
+        fn, key = _jit_cache_get(key)
+        jit_cache = "miss" if fn is None else "hit"
+        span.set_metadata(jit_cache=jit_cache)
+        if fn is None:
+            fn = make()
+            _jit_cache_put(key, fn)
+        out = fn(*args)
+        obs.emit("pregel.dispatch", t0=t0, t1=time.perf_counter(),
+                 jit_cache=jit_cache)
+    return out
+
+
 def run_pregel(
     spec: PregelSpec,
     sg: ShardedCOO,
@@ -378,13 +418,15 @@ def run_pregel(
         valid = ids < V
 
         def one_iter(state):
+            full = state
             if sharded and dist:
-                full = lax.all_gather(state, axis_model, tiled=True)
-            else:
-                full = state
-            src_state = full[jnp.clip(src, 0, full.shape[0] - 1)]
+                with jax.named_scope("pregel.exchange"):
+                    full = lax.all_gather(state, axis_model, tiled=True)
+            with jax.named_scope("pregel.gather"):
+                src_state = full[jnp.clip(src, 0, full.shape[0] - 1)]
+                if spec.needs_dst_state:
+                    dst_state = full[jnp.clip(dst, 0, full.shape[0] - 1)]
             if spec.needs_dst_state:
-                dst_state = full[jnp.clip(dst, 0, full.shape[0] - 1)]
                 msgs = spec.message(src_state, w, dst_state)
             else:
                 msgs = spec.message(src_state, w)
@@ -393,17 +435,20 @@ def run_pregel(
             agg = _local_combine(msgs, dst, V, v_local, start,
                                  spec.combine, spec.identity)
             if dist:
-                agg = _shard_combine(agg, spec.combine, axis_data)
-            if spec.global_value is not None:
-                g_src = agg if spec.global_over_agg else state
-                gval = spec.global_value(g_src, ids, valid)
-                if sharded and dist:
-                    gval = lax.psum(gval, axis_model)
-            else:
-                gval = jnp.float32(0.0)
-            new = spec.apply(state, agg, ids, gval)
-            vmask = valid.reshape(valid.shape + (1,) * (new.ndim - 1))
-            new = jnp.where(vmask, new, state)  # freeze padding slots
+                with jax.named_scope("pregel.exchange"):
+                    agg = _shard_combine(agg, spec.combine, axis_data)
+            with jax.named_scope("pregel.apply"):
+                if spec.global_value is not None:
+                    g_src = agg if spec.global_over_agg else state
+                    gval = spec.global_value(g_src, ids, valid)
+                    if sharded and dist:
+                        with jax.named_scope("pregel.exchange"):
+                            gval = lax.psum(gval, axis_model)
+                else:
+                    gval = jnp.float32(0.0)
+                new = spec.apply(state, agg, ids, gval)
+                vmask = valid.reshape(valid.shape + (1,) * (new.ndim - 1))
+                new = jnp.where(vmask, new, state)  # freeze padding slots
             return new
 
         if spec.halt is None:
@@ -419,11 +464,14 @@ def run_pregel(
         def step(carry):
             s, i, _ = carry
             new = one_iter(s)
-            conv_local = spec.halt(s, new, valid)
-            not_conv = jnp.logical_not(conv_local).astype(jnp.int32)
-            if dist:
-                axes = (axis_data, axis_model) if sharded else (axis_data,)
-                not_conv = lax.psum(not_conv, axes)
+            with jax.named_scope("pregel.halt"):
+                conv_local = spec.halt(s, new, valid)
+                not_conv = jnp.logical_not(conv_local).astype(jnp.int32)
+                if dist:
+                    axes = ((axis_data, axis_model) if sharded
+                            else (axis_data,))
+                    with jax.named_scope("pregel.exchange"):
+                        not_conv = lax.psum(not_conv, axes)
             return new, i + 1, not_conv == 0
 
         final, iters, _ = lax.while_loop(
@@ -435,28 +483,25 @@ def run_pregel(
     key = (spec, max_iters, _mesh_cache_key(mesh), axis_data, axis_model,
            V, v_local, sg.n_data, sg.n_model, sg.e_shard,
            init_state.shape, str(init_state.dtype))
-    fn, key = _jit_cache_get(key)
     if mesh is None:
         # Single-device: shards concatenated — treat as one big shard.
         # (2-D vertex-sharded layouts only make sense on a mesh.)
         assert not sharded, "vertex-sharded layout requires a mesh"
-        if fn is None:
-            fn = jax.jit(body)
-            _jit_cache_put(key, fn)
-        return fn(sg.src, sg.dst, sg.w, init_state)
+        return _dispatch(key, lambda: jax.jit(body),
+                         sg.src, sg.dst, sg.w, init_state)
 
-    if fn is None:
+    def make():
         edge_spec = P((axis_data, axis_model)) if sharded else P(axis_data)
         state_spec = P(axis_model) if sharded else P()
-        fn = jax.jit(jax.shard_map(
+        return jax.jit(jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(edge_spec, edge_spec, edge_spec, state_spec),
             out_specs=(state_spec, P()),
             check_vma=False,
         ))
-        _jit_cache_put(key, fn)
-    return fn(sg.src, sg.dst, sg.w, init_state)
+
+    return _dispatch(key, make, sg.src, sg.dst, sg.w, init_state)
 
 
 def _check_superstep_spec(spec: PregelSpec, what: str) -> None:
@@ -505,17 +550,21 @@ def run_pregel_fused(
         valid = ids < V        # all True; uniform halt/global signature
 
         def one_iter(state):
-            agg = superstep_ops.fused_superstep(
-                nbr, mask, w, state, message=spec.message,
-                op=spec.combine, identity=spec.identity,
-                message_dtype=spec.message_dtype, use_pallas=use_pallas,
-                block_rows=block_rows)
-            if spec.global_value is not None:
-                g_src = agg if spec.global_over_agg else state
-                gval = spec.global_value(g_src, ids, valid)
-            else:
-                gval = jnp.float32(0.0)
-            return spec.apply(state, agg, ids, gval)
+            # the kernel gathers inside its combine; its jnp path scopes
+            # the gather itself
+            with jax.named_scope("pregel.combine"):
+                agg = superstep_ops.fused_superstep(
+                    nbr, mask, w, state, message=spec.message,
+                    op=spec.combine, identity=spec.identity,
+                    message_dtype=spec.message_dtype,
+                    use_pallas=use_pallas, block_rows=block_rows)
+            with jax.named_scope("pregel.apply"):
+                if spec.global_value is not None:
+                    g_src = agg if spec.global_over_agg else state
+                    gval = spec.global_value(g_src, ids, valid)
+                else:
+                    gval = jnp.float32(0.0)
+                return spec.apply(state, agg, ids, gval)
 
         if spec.halt is None:
             def fori(_, s):
@@ -530,7 +579,9 @@ def run_pregel_fused(
         def step(carry):
             s, i, _ = carry
             new = one_iter(s)
-            return new, i + 1, spec.halt(s, new, valid)
+            with jax.named_scope("pregel.halt"):
+                done = spec.halt(s, new, valid)
+            return new, i + 1, done
 
         final, iters, _ = lax.while_loop(
             cond, step, (state, jnp.int32(0), jnp.array(False)))
@@ -538,11 +589,8 @@ def run_pregel_fused(
 
     key = ("fused", spec, max_iters, V, ell.nbr.shape, use_pallas,
            block_rows, init_state.shape, str(init_state.dtype))
-    fn, key = _jit_cache_get(key)
-    if fn is None:
-        fn = jax.jit(body)
-        _jit_cache_put(key, fn)
-    return fn(ell.nbr, ell.mask, ell.w, init_state)
+    return _dispatch(key, lambda: jax.jit(body),
+                     ell.nbr, ell.mask, ell.w, init_state)
 
 
 def run_pregel_frontier(
@@ -650,15 +698,17 @@ def run_pregel_frontier(
             def blk(j, acc):
                 fb = lax.dynamic_slice(frontier, (j * B,), (B,))
                 row = jnp.clip(fb, 0, V - 1)
-                rn = nbr[row]                  # (B, K), sentinel V
-                rm = msk[row] & (fb < V)[:, None]
-                rw = w[row]
-                src = jnp.broadcast_to(state[row][:, None],
-                                       (B, K) + trailing)
+                with jax.named_scope("pregel.gather"):
+                    rn = nbr[row]              # (B, K), sentinel V
+                    rm = msk[row] & (fb < V)[:, None]
+                    rw = w[row]
+                    src = jnp.broadcast_to(state[row][:, None],
+                                           (B, K) + trailing)
+                    if delta:
+                        prev_src = jnp.broadcast_to(prev[row][:, None],
+                                                    (B, K) + trailing)
                 msgs = spec.message(src, rw)
                 if delta:
-                    prev_src = jnp.broadcast_to(prev[row][:, None],
-                                                (B, K) + trailing)
                     pm = spec.message(prev_src, rw)
                     msgs = msgs - jnp.where(first, jnp.zeros_like(pm), pm)
                 if spec.message_dtype is not None:
@@ -668,24 +718,27 @@ def run_pregel_frontier(
                     m = m.reshape(m.shape + (1,) * (msgs.ndim - m.ndim))
                 msgs = jnp.where(m, msgs.astype(agg_dtype), fill)
                 # padded/inactive slots aim at the sentinel row V
-                dst_f = jnp.where(rm, rn, V).reshape(-1)
-                mf = msgs.reshape((B * K,) + msgs.shape[2:])
-                return scatter(acc, dst_f, mf)
+                with jax.named_scope("pregel.combine"):
+                    dst_f = jnp.where(rm, rn, V).reshape(-1)
+                    mf = msgs.reshape((B * K,) + msgs.shape[2:])
+                    return scatter(acc, dst_f, mf)
 
             return lax.fori_loop(0, n_blocks, blk, acc)
 
         def one_superstep(s, agg):
-            if spec.global_value is not None:
-                g_src = agg if spec.global_over_agg else s
-                gval = spec.global_value(g_src, ids, valid)
-            else:
-                gval = jnp.float32(0.0)
-            return spec.apply(s, agg, ids, gval)
+            with jax.named_scope("pregel.apply"):
+                if spec.global_value is not None:
+                    g_src = agg if spec.global_over_agg else s
+                    gval = spec.global_value(g_src, ids, valid)
+                else:
+                    gval = jnp.float32(0.0)
+                return spec.apply(s, agg, ids, gval)
 
         def halt_of(s, new):
             if spec.halt is None:
                 return jnp.array(False)
-            return spec.halt(s, new, valid)
+            with jax.named_scope("pregel.halt"):
+                return spec.halt(s, new, valid)
 
         if delta:
             act0 = jnp.ones((V,), bool)     # round 1 seeds the full sum
@@ -754,9 +807,6 @@ def run_pregel_frontier(
 
     key = ("frontier", spec, max_iters, V, K, B,
            init_state.shape, str(init_state.dtype), seeded, profile)
-    fn, key = _jit_cache_get(key)
-    if fn is None:
-        fn = jax.jit(body)
-        _jit_cache_put(key, fn)
     args = (jnp.asarray(init_active, bool),) if seeded else ()
-    return fn(ell.nbr, ell.mask, ell.w, init_state, *args)
+    return _dispatch(key, lambda: jax.jit(body),
+                     ell.nbr, ell.mask, ell.w, init_state, *args)

@@ -79,6 +79,13 @@ module is that service tier:
 ``GraphPlatform`` (``repro.core.query``) survives as a thin per-graph
 facade over these primitives: its synchronous ``query`` is
 :meth:`GraphAnalyticsService.call` on a one-entry catalog.
+
+The host work of a ticket is on the profiler's timeline, next to the
+device's: ``jax.profiler.TraceAnnotation`` regions ``service.submit``
+(with ``service.plan`` around the planner call), ``service.execute``
+(each attempt, where the tracer's ``execute`` span opens and closes) and
+``service.resolve`` (the bookkeeping after an execution).  With no
+profile being collected an annotation is one cheap check.
 """
 from __future__ import annotations
 
@@ -87,6 +94,8 @@ import threading
 import time
 from collections import OrderedDict, deque
 from typing import Any, Iterable, Optional, Sequence
+
+import jax
 
 from repro.core import graph as G
 from repro.core import obs
@@ -939,9 +948,23 @@ class GraphAnalyticsService:
         fuse — the seed is per-snapshot state a shared batch program
         cannot carry.
         """
-        ctx = self.context(graph_name, as_of)
-        seed, seed_mode = self._seed_for(ctx, q)
-        plan = ctx.plan(q, seed_mode=seed_mode)
+        # the tracer's clock, so that its spans and these reads agree
+        clock = self.tracer.clock if self.tracer is not None \
+            else time.perf_counter
+        with jax.profiler.TraceAnnotation("service.submit"):
+            t_submit = clock()
+            ctx = self.context(graph_name, as_of)
+            seed, seed_mode = self._seed_for(ctx, q)
+            with jax.profiler.TraceAnnotation("service.plan"):
+                t_plan = clock()
+                plan = ctx.plan(q, seed_mode=seed_mode)
+                plan_span = (t_plan, clock())
+            return self._admit(ctx, graph_name, q, plan, seed, t_submit,
+                               plan_span)
+
+    def _admit(self, ctx: GraphContext, graph_name: str, q, plan: P.Plan,
+               seed, t_submit: float, plan_span: tuple) -> QueryTicket:
+        """The admission half of ``submit``: tier, queue, ticket."""
         if plan.mode == "full":
             seed = None
         est = P.plan_cost(plan)
@@ -996,7 +1019,7 @@ class GraphAnalyticsService:
                                 "variant": planned.variant,
                                 "est_s": planned.est_s}
                 self.tracer.on_submit(
-                    ticket, ticket.queued_at,
+                    ticket, t_submit, plan_span,
                     admission={"est_s": est,
                                "budget_s": self.admission_budget_s,
                                "threshold_s": self.interactive_threshold_s,
@@ -1377,32 +1400,31 @@ class GraphAnalyticsService:
         for attempt in range(1, self.retry.max_attempts + 1):
             for t in tickets:
                 t.attempts = attempt
-            handle = None
-            if self.tracer is not None:
-                handle = self.tracer.on_attempt_start(ids, attempt,
-                                                      fused=fused)
-            try:
-                out = thunk()
-            except Exception as e:
-                if last is not None and e is not last \
-                        and e.__cause__ is None:
-                    e.__cause__ = last       # preserve the attempt chain
-                last = e
-                if handle is not None:
-                    self.tracer.on_attempt_end(handle, e)
-                if not self.retry.retryable(e) \
-                        or attempt >= self.retry.max_attempts:
-                    return None, e
-                with self._lock:
-                    self.stats["retries"] += 1
+            error = None
+            with jax.profiler.TraceAnnotation("service.execute"):
+                handle = None
                 if self.tracer is not None:
-                    self.tracer.on_retry(ids, attempt,
-                                         schedule[attempt - 1])
-                time.sleep(schedule[attempt - 1])
-            else:
+                    handle = self.tracer.on_attempt_start(ids, attempt,
+                                                          fused=fused)
+                try:
+                    out = thunk()
+                except Exception as e:
+                    if last is not None and e is not last \
+                            and e.__cause__ is None:
+                        e.__cause__ = last   # preserve the attempt chain
+                    last = error = e
                 if handle is not None:
-                    self.tracer.on_attempt_end(handle)
+                    self.tracer.on_attempt_end(handle, error)
+            if error is None:
                 return out, None
+            if not self.retry.retryable(error) \
+                    or attempt >= self.retry.max_attempts:
+                return None, error
+            with self._lock:
+                self.stats["retries"] += 1
+            if self.tracer is not None:
+                self.tracer.on_retry(ids, attempt, schedule[attempt - 1])
+            time.sleep(schedule[attempt - 1])
         return None, last                    # pragma: no cover
 
     def _execute_unit(self, unit: _WorkUnit, finished: list) -> None:
@@ -1431,10 +1453,18 @@ class GraphAnalyticsService:
                                 profile=profile),
             t.ticket_id, [t])
         wall = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("service.resolve"):
+            self._resolve_solo(t, r, err, wall)
+        finished.append(t)
+
+    def _resolve_solo(self, t: QueryTicket, r: Optional[QueryResult],
+                      err: Optional[BaseException], wall: float) -> None:
+        """The bookkeeping after one solo execution: dead letter, or
+        accuracy sample, incremental record, cache entry and result."""
         if err is not None:
             self._dead_letter([t], err)
-            finished.append(t)
             return
+        ctx = t.context
         self._accuracy.record(t.query.algorithm, t.plan.engine,
                               t.plan.variant, t.plan.pool,
                               est_s=t.est_s, wall_s=wall,
@@ -1452,7 +1482,6 @@ class GraphAnalyticsService:
             self._finish(t, r)
             self._log(t.plan.engine, t.tier, [t], fused=False,
                       algorithm=t.query.algorithm)
-        finished.append(t)
 
     @staticmethod
     def _result_attrs(r: QueryResult, wall: float) -> dict:
@@ -1514,10 +1543,19 @@ class GraphAnalyticsService:
                 profile=profile),
             run[0].ticket_id, run, fused=True)
         wall = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("service.resolve"):
+            self._resolve_group(engine, defn, run, r, err, wall)
+        finished.extend(run)
+
+    def _resolve_group(self, engine: str, defn: R.AlgorithmDef,
+                       run: list[QueryTicket], r: Optional[list],
+                       err: Optional[BaseException], wall: float) -> None:
+        """The bookkeeping after one fused execution: dead letters, or
+        one accuracy sample, cache entries and results."""
         if err is not None:
             self._dead_letter(run, err)
-            finished.extend(run)
             return
+        ctx = run[0].context
         # one fused execution, one accuracy sample: the group's shared
         # wall against the head ticket's estimate, width recorded
         head = run[0]
@@ -1550,7 +1588,6 @@ class GraphAnalyticsService:
                 self._finish(t, res)
             self._log(engine, "batch", run, fused=True,
                       algorithm=defn.name)
-        finished.extend(run)
 
     def _finish(self, t: QueryTicket, r: QueryResult) -> None:
         with self._cond:

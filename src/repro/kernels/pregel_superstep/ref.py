@@ -43,7 +43,8 @@ def superstep_ref(nbr, mask, w, x, *, message, op: str, identity,
     Returns [V] or [V, ...] aggregates in the message dtype (cast to
     ``message_dtype`` first when set — the reduced-precision channel).
     """
-    vals = jnp.take(x, jnp.clip(nbr, 0, x.shape[0] - 1), axis=0)
+    with jax.named_scope("pregel.gather"):
+        vals = jnp.take(x, jnp.clip(nbr, 0, x.shape[0] - 1), axis=0)
     msgs = message(vals, w)
     if message_dtype is not None:
         msgs = msgs.astype(message_dtype)

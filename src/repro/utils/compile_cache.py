@@ -6,6 +6,12 @@ compile; library modules never do.  The cache directory is part of each
 entry's key, so it must not move between runs: it is
 ``$JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads the variable
 itself), else the fixed ``<checkout>/.jax_cache``.
+
+An entry's key includes the program's metadata (``op_name``, source
+locations), which JAX leaves out by default: a program whose
+``jax.named_scope``s changed, and nothing else, would otherwise load an
+executable compiled under the old names, and a profiler trace of it
+would show those.
 """
 from __future__ import annotations
 
@@ -19,6 +25,7 @@ CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 def enable_compile_cache() -> str:
     """Turn on the persistent compilation cache; returns its directory."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -126,17 +126,21 @@ def test_four_chip_phase_rehearsal():
 
 @pytest.mark.parametrize("env_dir", [False, True])
 def test_compile_cache_directory(tmp_path, env_dir):
-    """Set: JAX's own variable wins and nothing else is configured.
-    Unset: the fixed <checkout>/.jax_cache."""
+    """Set: JAX's own variable wins.  Unset: the fixed
+    <checkout>/.jax_cache.  Either way an entry's key holds the
+    program's metadata, so a renamed scope compiles anew."""
     extra = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)} if env_dir else {}
     code = ("import jax\n"
             "from repro.utils.compile_cache import enable_compile_cache\n"
             "print(enable_compile_cache())\n"
-            "print(jax.config.jax_compilation_cache_dir)\n")
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "print(jax.config.jax_compilation_cache_include_metadata_in_key)"
+            "\n")
     env = _env()
     env.update(extra)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300, env=env, cwd=ROOT)
-    chosen, configured = r.stdout.split()
+    chosen, configured, metadata = r.stdout.split()
     want = str(tmp_path) if env_dir else os.path.join(ROOT, ".jax_cache")
     assert chosen == configured == want
+    assert metadata == "True"

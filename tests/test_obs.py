@@ -10,8 +10,14 @@ materialize in the tree; the Chrome trace export validates against the
 trace-event schema; ``metrics_text()`` round-trips ``metrics()``; and
 tracing never changes a single result byte.
 """
+import dataclasses
+import glob
+import itertools
 import math
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,8 +25,11 @@ from repro.core import graph as G
 from repro.core import obs
 from repro.core import planner as P
 from repro.core import pools as PL
+from repro.core import pregel
 from repro.core import registry as R
+from repro.core.algorithms import traversal
 from repro.core.engines import LocalEngine
+from repro.core.partition import partition_1d
 from repro.core.query import GraphQuery
 from repro.core.runtime import LatencyHistogram, RetryPolicy
 from repro.core.service import GraphAnalyticsService
@@ -94,6 +103,24 @@ def test_span_tree_full_lifecycle(graph):
     for needle in ("ticket #", "admission", "queue-wait", "attempt",
                    "resolve", "status=done"):
         assert needle in text
+
+
+def test_plan_span_times_the_planner_call(graph):
+    """The plan span is the planner call, read at the clock reads that
+    bound it inside submit; admission is the instant the ticket was
+    queued; submit spans the whole call."""
+    ticks = itertools.count()
+    svc = _traced_service(graph, tracer=obs.Tracer(
+        trace_depth=8, clock=lambda: float(next(ticks))))
+    t = svc.submit("g", GraphQuery.bfs([0]))
+    tr = svc.tracer.trace(t.ticket_id)
+    submit, plan, adm = (tr.find(n) for n in ("submit", "plan",
+                                              "admission"))
+    # reads in order: submit starts, plan starts, plan ends, queued
+    assert tr.root.t0 == submit.t0
+    assert (plan.t0, plan.t1) == (submit.t0 + 1, submit.t0 + 2)
+    assert adm.t0 == adm.t1 == submit.t1 == plan.t1 + 1
+    svc.result(t)
 
 
 def test_plan_span_records_all_candidates(graph):
@@ -298,7 +325,6 @@ def test_superstep_counters_per_variant(graph):
         ss = r.meta["superstep"]
         assert ss["variant"] == variant
         assert ss["iterations"] >= 1
-        assert ss["halt_step"] == ss["iterations"]
         assert ss["halted"] == (ss["iterations"] < ss["max_iters"])
         assert ss["message_bytes"] > 0
         assert np.asarray(r.value).tobytes() == ref.tobytes()
@@ -492,6 +518,17 @@ def test_metrics_text_roundtrips_metrics(graph):
     assert parsed["gas_counters_executed"] >= 1
 
 
+def test_metric_names_keep_exponent_signs_apart():
+    """Histogram bucket keys differ only in their exponent's sign: their
+    metric names must differ too."""
+    small = obs._metric_name("gas", ("latency", "batch", "le_1e-02"))
+    large = obs._metric_name("gas", ("latency", "batch", "le_1e+02"))
+    assert (small, large) == ("gas_latency_batch_le_1em02",
+                              "gas_latency_batch_le_1ep02")
+    assert obs._metric_name("gas", ("pools", "pool-a", "x.y")) == \
+        "gas_pools_pool_a_x_y"
+
+
 def test_latency_window_exact_flag():
     h = LatencyHistogram(max_samples=4)
     for x in (0.1, 0.2, 0.3):
@@ -595,3 +632,138 @@ def test_infeasible_candidates_carry_the_reason():
     assert not local.feasible
     assert not math.isfinite(local.est_s)
     assert local.note == "exceeds local memory budget"
+
+
+# ---------------------------------------------------------------------------
+# The superstep's phases and the service's host work on the profiler trace
+# ---------------------------------------------------------------------------
+
+V_SCOPES = 64
+
+
+def _scoped_ops(fn, *args, kinds=("gather", "scatter")) -> list:
+    """``(kind, innermost pregel.* scope or None)`` of each instruction
+    of a kind in ``kinds`` in the compiled program's HLO."""
+    out = []
+    for line in jax.jit(fn).lower(*args).compile().as_text().splitlines():
+        m = re.search(r"= \S+ (\w+)\(", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if m and m.group(1) in kinds and op:
+            scopes = [p for p in op.group(1).split("/")
+                      if p.startswith("pregel.")]
+            out.append((m.group(1), scopes[-1] if scopes else None))
+    return out
+
+
+def _scopes_graph():
+    rng = np.random.default_rng(5)
+    src = rng.integers(0, V_SCOPES, 4 * V_SCOPES)
+    dst = rng.integers(0, V_SCOPES, 4 * V_SCOPES)
+    return G.build_coo(src, dst, V_SCOPES, symmetrize=True)
+
+
+_SUM_SPEC = pregel.PregelSpec(
+    message=lambda x, w: x * w, combine="sum",
+    apply=lambda s, agg, ids, gv: 0.5 * s + agg, identity=0.0,
+    elementwise_message=True)
+
+
+@pytest.mark.parametrize("combine", ["sum", "min"])
+def test_dense_superstep_ops_carry_their_phase(combine):
+    """The gather, the segment scatter and (for min/max) the empty-count
+    scatter of the dense program carry their phase's scope in their HLO
+    ``op_name``."""
+    spec = _SUM_SPEC if combine == "sum" else traversal._SSSP_SPEC
+    sg = partition_1d(_scopes_graph(), 1)
+    init = jnp.ones((V_SCOPES,), jnp.float32)
+
+    def program(src, dst, w, state):
+        edges = dataclasses.replace(sg, src=src, dst=dst, w=w)
+        return pregel.run_pregel(spec, edges, state, 4)
+
+    ops = _scoped_ops(program, sg.src, sg.dst, sg.w, init)
+    assert ("gather", "pregel.gather") in ops
+    scatters = sorted(s for k, s in ops if k == "scatter")
+    if combine == "sum":
+        assert scatters == ["pregel.combine"]
+    else:
+        assert scatters == ["pregel.combine", "pregel.combine_empty"]
+
+
+@pytest.mark.parametrize("variant", ["fused", "frontier"])
+def test_variant_superstep_ops_carry_their_phase(variant):
+    """The fused variant's gather and reduce, and the frontier
+    variant's row gathers and scatter, carry their phase's scope."""
+    g = _scopes_graph()
+    src = np.asarray(g.src)[: g.n_edges]
+    dst = np.asarray(g.dst)[: g.n_edges]
+    if variant == "fused":
+        width = int(np.bincount(dst, minlength=V_SCOPES).max())
+        ell = G.build_ell(src, dst, V_SCOPES, width, direction="in")
+        run, kinds = pregel.run_pregel_fused, ("gather", "reduce")
+    else:
+        width = int(np.bincount(src, minlength=V_SCOPES).max())
+        ell = G.build_ell(src, dst, V_SCOPES, width, direction="out")
+        run, kinds = pregel.run_pregel_frontier, ("gather", "scatter")
+    init = jnp.full((V_SCOPES,), jnp.inf).at[0].set(0.0)
+    ops = _scoped_ops(lambda e, s: run(traversal._SSSP_SPEC, e, s, 4),
+                      ell, init, kinds=kinds)
+    assert ops and all(s == "pregel.gather" for k, s in ops
+                       if k == "gather")
+    assert (kinds[1], "pregel.combine") in ops
+
+
+def _host_spans(log_dir) -> list:
+    """``(name, start_ns, end_ns, stats)`` of the ``service.``/``pregel.``
+    events on the host planes of the one trace under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return sorted(
+        ((e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+         for plane in ProfileData.from_file(path).planes
+         if plane.name.startswith("/host:")
+         for line in plane.lines for e in line.events
+         if e.name.startswith(("service.", "pregel."))),
+        key=lambda e: e[1])
+
+
+def test_service_and_dispatch_spans_on_the_profiler_trace(tmp_path):
+    """Two PageRank jobs and two WCC jobs through a traced service under
+    the profiler: each job's host work is on the timeline, its program
+    call a ``pregel.dispatch`` inside ``service.execute``.  PageRank
+    builds its program anew every job (two misses), WCC reuses its
+    (miss, then hit).  The tracer's dispatch events bracket the same
+    work inside each ticket's execute span."""
+    # a vertex count no other test uses, so WCC's first program is new
+    n = 173
+    src, dst = S.user_follow_graph(n, 4.0, seed=3)
+    svc = GraphAnalyticsService(trace_depth=16, cache_size=0)
+    svc.add_graph("g", G.build_coo(src, dst, n, symmetrize=True))
+    queries = [GraphQuery.pagerank(max_iters=3)] * 2 \
+        + [GraphQuery.connected_components()] * 2
+    tickets = []
+    with jax.profiler.trace(str(tmp_path)):
+        for q in queries:
+            tickets.append(svc.submit("g", q))
+            svc.result(tickets[-1])
+    spans = _host_spans(tmp_path)
+    names = [s[0] for s in spans]
+    assert names.count("service.submit") == names.count("service.plan") \
+        == names.count("service.execute") == 4
+    assert names.count("service.resolve") == 4
+    executes = [s for s in spans if s[0] == "service.execute"]
+    dispatches = [s for s in spans if s[0] == "pregel.dispatch"]
+    assert [d[3].get("jit_cache") for d in dispatches] == [
+        "miss", "miss", "miss", "hit"]
+    for (_, a, b, _), (_, c, d, _) in zip(executes, dispatches):
+        assert a <= c <= d <= b
+    for submit in (s for s in spans if s[0] == "service.submit"):
+        assert any(p[0] == "service.plan" and submit[1] <= p[1]
+                   and p[2] <= submit[2] for p in spans)
+    events = [a for _, kind, a in svc.tracer.events
+              if kind == "pregel.dispatch"]
+    assert [a["jit_cache"] for a in events] == ["miss", "miss", "miss",
+                                                "hit"]
+    for t, a in zip(tickets, events):
+        execute = svc.tracer.trace(t.ticket_id).find("execute")
+        assert execute.t0 <= a["t0"] <= a["t1"] <= execute.t1
