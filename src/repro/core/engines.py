@@ -615,8 +615,10 @@ class LocalEngine(Engine):
     Holds the graph in exact COO (+ the degree-capped ELL for motif/
     similarity queries).  ``use_pallas`` routes the fused superstep and
     the ELL-intersect triangle path through their Pallas kernels instead
-    of the jnp references — same numerics.  Off by default: the served
-    path runs XLA programs only.
+    of the jnp references — same numerics.  Off by default.  The dense
+    superstep's combine of ``[E]`` messages runs the ``segment_runs``
+    kernel on a TPU either way: this engine's one-shard edge shards carry
+    run offsets.
     """
 
     name = "local"

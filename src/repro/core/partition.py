@@ -24,6 +24,7 @@ land on the default device.
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,12 @@ class ShardedCOO:
     shard ``i``.  For 2-D partitioning ``n_shards == n_data * n_model``
     and shard ``(d, m)`` sits at index ``d * n_model + m`` (mesh-major
     order for ``PartitionSpec(('data', 'model'))``).
+
+    ``in_offsets`` (``[n_vertices + 1]`` int32, or None): vertex ``v``'s
+    in-edges are slots ``in_offsets[v]:in_offsets[v + 1]``.  Set only by
+    ``partition_1d`` for one shard with no mesh, when the slots are
+    sorted by destination (``build_coo``'s order); the dense superstep
+    then combines each vertex's run of slots instead of scattering.
     """
 
     src: jax.Array
@@ -52,6 +59,7 @@ class ShardedCOO:
     n_model: int          # 1 for 1-D partitioning
     e_shard: int
     v_local: int          # vertices owned per model shard (V for 1-D)
+    in_offsets: Optional[jax.Array] = None
 
     @property
     def vertex_layout(self) -> str:
@@ -85,9 +93,22 @@ def _place(arrays, mesh, spec):
     return tuple(jax.device_put(a, sharding) for a in arrays)
 
 
+def _run_offsets(dst: np.ndarray, n_vertices: int) -> Optional[np.ndarray]:
+    """Run offsets of destination-sorted slots: ``[n_vertices + 1]`` int32
+    with vertex ``v``'s slots at ``offsets[v]:offsets[v + 1]``; None when
+    ``dst`` is not non-decreasing or holds a negative id."""
+    if dst.size and (dst[0] < 0 or np.any(dst[1:] < dst[:-1])):
+        return None
+    return np.searchsorted(dst, np.arange(n_vertices + 1)).astype(np.int32)
+
+
 def partition_1d(g: GraphCOO, n_data: int, pad_multiple: int = 256,
                  mesh=None) -> ShardedCOO:
-    """Round-robin edge split over the data axis (vertex state replicated)."""
+    """Round-robin edge split over the data axis (vertex state replicated).
+
+    One shard with no mesh keeps ``build_coo``'s destination order (the
+    padding, ``dst = V``, sorts last), and gets ``in_offsets`` when the
+    slots are indeed sorted."""
     src = np.asarray(g.src)[: g.n_edges]
     dst = np.asarray(g.dst)[: g.n_edges]
     w = np.asarray(g.w)[: g.n_edges]
@@ -98,10 +119,16 @@ def partition_1d(g: GraphCOO, n_data: int, pad_multiple: int = 256,
         groups.append((src[sel], dst[sel], w[sel]))
     s, dd, ww = _place(_pack_shards(groups, e_shard, np.int32(g.n_vertices)),
                        mesh, P("data"))
+    offsets = None
+    if n_data == 1 and mesh is None:
+        offsets = _run_offsets(dst, g.n_vertices)
+        if offsets is not None:
+            offsets = jnp.asarray(offsets)
     return ShardedCOO(
         src=s, dst=dd, w=ww,
         n_vertices=g.n_vertices, n_edges=g.n_edges,
         n_data=n_data, n_model=1, e_shard=e_shard, v_local=g.n_vertices,
+        in_offsets=offsets,
     )
 
 

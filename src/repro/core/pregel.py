@@ -23,7 +23,16 @@ on a mesh); an op under nested scopes belongs to the innermost.  A scope
 is metadata only: the program and its fusions are the same without it.
 On the host, each ``run_pregel*`` call is one ``pregel.dispatch`` span
 from its jit-cache lookup to the return of the program call
-(``_dispatch``).
+(``_dispatch``), tagged with the combine that ran.
+
+The dense combine takes one of two paths, chosen by its input.  When the
+edge shards carry ``in_offsets`` (one device, slots sorted by
+destination) and the spec sends ``[E]`` messages under one monoid, each
+vertex's contiguous run of messages is reduced by ``segment_runs`` and
+read at the run's end (``combine=runs``; the Pallas kernel on a TPU, the
+scatter on other backends); otherwise messages are scattered by
+destination with ``jax.ops.segment_*`` (``combine=scatter``).  Both give
+the same aggregate: min/max exactly, sums in float32 in another order.
 
 Everything is statically shaped: padded edges carry the sentinel vertex id
 and are dropped at the segment-combine.  Convergence is decided *inside*
@@ -48,6 +57,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import obs
 from repro.core.partition import ShardedCOO
+from repro.kernels.segment_runs import segment_runs
 
 Array = jax.Array
 
@@ -267,6 +277,29 @@ _SEG = {
 }
 
 
+def _scalar_messages(spec: PregelSpec, sg: ShardedCOO, state) -> bool:
+    """Whether ``spec`` sends one scalar a slot (``[E]`` messages) under one
+    monoid: the messages the runs combine takes.  Wider messages (batched
+    ``[E, B]`` specs, grouped monoids, bitset rows) keep the scatter,
+    whose temporaries do not grow with the message width."""
+    if isinstance(spec.combine, tuple):
+        return False
+    shape = (sg.src.shape[0],) + tuple(state.shape[1:])
+    return _message_is_scalar(spec.message, spec.needs_dst_state, shape,
+                              jnp.dtype(state.dtype), jnp.dtype(sg.w.dtype))
+
+
+# keyed by the message function alone (not the spec, whose closures may
+# hold vertex arrays), as many entries as the jit cache
+@functools.lru_cache(maxsize=64)
+def _message_is_scalar(message, needs_dst_state, shape, dtype,
+                       w_dtype) -> bool:
+    row = jax.ShapeDtypeStruct(shape, dtype)
+    w = jax.ShapeDtypeStruct(shape[:1], w_dtype)
+    args = (row, w, row) if needs_dst_state else (row, w)
+    return jax.eval_shape(message, *args).ndim == 1
+
+
 def _psum_like(x: Array, op: str, axis) -> Array:
     if op == "sum":
         return lax.psum(x, axis)
@@ -277,12 +310,25 @@ def _psum_like(x: Array, op: str, axis) -> Array:
     raise ValueError(op)
 
 
-def _local_combine(msgs, dst, n_vertices, v_local, start, op, identity):
+def _local_combine(msgs, dst, n_vertices, v_local, start, op, identity,
+                   offsets=None):
     """Segment-combine messages into the locally-owned vertex range.
 
     Grouped ``op`` splits the message's last axis into ``(op, width)``
-    column groups, each combined under its own monoid.
+    column groups, each combined under its own monoid.  With ``offsets``
+    (``ShardedCOO.in_offsets``: one device, slots sorted by destination;
+    ``[E]`` messages under one monoid) each vertex's run of slots is
+    reduced in place of the scatter, and a vertex with no message is one
+    whose run is empty.
     """
+    if offsets is not None:
+        with jax.named_scope("pregel.combine"):
+            agg = segment_runs(msgs, dst, offsets, op)
+        if op in ("min", "max"):
+            with jax.named_scope("pregel.combine_empty"):
+                agg = jnp.where(offsets[1:] == offsets[:-1],
+                                jnp.asarray(identity, agg.dtype), agg)
+        return agg
     if isinstance(op, tuple):
         parts, c0 = [], 0
         for (o, width), ident in zip(op, identity):
@@ -364,25 +410,27 @@ def _jit_cache_put(key, fn) -> None:
             _JIT_CACHE.popitem(last=False)
 
 
-def _dispatch(key, make: Callable, *args):
+def _dispatch(key, make: Callable, *args, combine: str):
     """Call the program cached under ``key`` (``make()`` builds it on a
     miss) as one ``pregel.dispatch`` span: the host time from the lookup
     to the return of the program call, a missed program's trace and
     compile included.  The span is a profiler annotation tagged
-    ``jit_cache=hit|miss`` and, at the same two clock reads, an
-    ``obs.emit`` event carrying ``t0``/``t1`` (``time.perf_counter``)
-    and ``jit_cache`` for an installed tracer."""
+    ``jit_cache=hit|miss`` and ``combine`` (the program's combine: the
+    dense path's ``runs`` or ``scatter``, or the variants' ``ell`` and
+    ``frontier``) and, at the same two clock reads, an ``obs.emit`` event
+    carrying ``t0``/``t1`` (``time.perf_counter``), ``jit_cache`` and
+    ``combine`` for an installed tracer."""
     with jax.profiler.TraceAnnotation("pregel.dispatch") as span:
         t0 = time.perf_counter()
         fn, key = _jit_cache_get(key)
         jit_cache = "miss" if fn is None else "hit"
-        span.set_metadata(jit_cache=jit_cache)
+        span.set_metadata(jit_cache=jit_cache, combine=combine)
         if fn is None:
             fn = make()
             _jit_cache_put(key, fn)
         out = fn(*args)
         obs.emit("pregel.dispatch", t0=t0, t1=time.perf_counter(),
-                 jit_cache=jit_cache)
+                 jit_cache=jit_cache, combine=combine)
     return out
 
 
@@ -399,15 +447,22 @@ def run_pregel(
 
     Returns ``(final_state [V or n_model*v_local], iterations_run)``.
     With ``mesh=None`` runs the same program on one device (the engine the
-    planner picks for medium graphs still shares this code path).
+    planner picks for medium graphs still shares this code path); there
+    the combine reduces destination runs when ``sg.in_offsets`` is set
+    and the spec sends ``[E]`` messages.
     """
     check_precision(spec)
     V = sg.n_vertices
     v_local = sg.v_local
     sharded = sg.vertex_layout == "sharded"
+    runs = (sg.in_offsets,) if (mesh is None and sg.in_offsets is not None
+                                and _scalar_messages(spec, sg, init_state)) \
+        else ()
+    combine = "runs" if runs else "scatter"
 
-    def body(src, dst, w, state):
+    def body(src, dst, w, state, *extra):
         """Executes per-device under shard_map (or directly, single device)."""
+        offsets = extra[0] if extra else None
         dist = mesh is not None
         if sharded:
             m_idx = lax.axis_index(axis_model) if dist else 0
@@ -433,7 +488,7 @@ def run_pregel(
             if spec.message_dtype is not None:
                 msgs = msgs.astype(spec.message_dtype)
             agg = _local_combine(msgs, dst, V, v_local, start,
-                                 spec.combine, spec.identity)
+                                 spec.combine, spec.identity, offsets)
             if dist:
                 with jax.named_scope("pregel.exchange"):
                     agg = _shard_combine(agg, spec.combine, axis_data)
@@ -482,13 +537,14 @@ def run_pregel(
     # (the 'consistent query performance' property of the local engine)
     key = (spec, max_iters, _mesh_cache_key(mesh), axis_data, axis_model,
            V, v_local, sg.n_data, sg.n_model, sg.e_shard,
-           init_state.shape, str(init_state.dtype))
+           init_state.shape, str(init_state.dtype), combine)
     if mesh is None:
         # Single-device: shards concatenated — treat as one big shard.
         # (2-D vertex-sharded layouts only make sense on a mesh.)
         assert not sharded, "vertex-sharded layout requires a mesh"
         return _dispatch(key, lambda: jax.jit(body),
-                         sg.src, sg.dst, sg.w, init_state)
+                         sg.src, sg.dst, sg.w, init_state, *runs,
+                         combine=combine)
 
     def make():
         edge_spec = P((axis_data, axis_model)) if sharded else P(axis_data)
@@ -501,7 +557,8 @@ def run_pregel(
             check_vma=False,
         ))
 
-    return _dispatch(key, make, sg.src, sg.dst, sg.w, init_state)
+    return _dispatch(key, make, sg.src, sg.dst, sg.w, init_state,
+                     combine=combine)
 
 
 def _check_superstep_spec(spec: PregelSpec, what: str) -> None:
@@ -590,7 +647,7 @@ def run_pregel_fused(
     key = ("fused", spec, max_iters, V, ell.nbr.shape, use_pallas,
            block_rows, init_state.shape, str(init_state.dtype))
     return _dispatch(key, lambda: jax.jit(body),
-                     ell.nbr, ell.mask, ell.w, init_state)
+                     ell.nbr, ell.mask, ell.w, init_state, combine="ell")
 
 
 def run_pregel_frontier(
@@ -809,4 +866,5 @@ def run_pregel_frontier(
            init_state.shape, str(init_state.dtype), seeded, profile)
     args = (jnp.asarray(init_active, bool),) if seeded else ()
     return _dispatch(key, lambda: jax.jit(body),
-                     ell.nbr, ell.mask, ell.w, init_state, *args)
+                     ell.nbr, ell.mask, ell.w, init_state, *args,
+                     combine="frontier")

@@ -1,4 +1,8 @@
 # Pallas TPU kernels for the platform's compute hot spots:
+#   segment_runs     — the dense superstep's combine: a segmented scan
+#                      along destination-sorted runs of edge slots; on
+#                      the served path whenever the edge shards carry
+#                      run offsets (one device).
 #   ell_intersect    — sorted-row intersection counts, the inner loop of
 #                      degree-ordered triangle counting; compiles for v5e.
 #   ell_combine      — ELL gather+combine (SpMV / hash-to-min) and

@@ -10,7 +10,9 @@ materialize in the tree; the Chrome trace export validates against the
 trace-event schema; ``metrics_text()`` round-trips ``metrics()``; and
 tracing never changes a single result byte.
 """
+import collections
 import dataclasses
+import functools
 import glob
 import itertools
 import math
@@ -34,6 +36,7 @@ from repro.core.query import GraphQuery
 from repro.core.runtime import LatencyHistogram, RetryPolicy
 from repro.core.service import GraphAnalyticsService
 from repro.data import synthetic as S
+from repro.kernels.segment_runs import segment_runs_pallas
 
 N = 200
 
@@ -674,7 +677,9 @@ def test_dense_superstep_ops_carry_their_phase(combine):
     scatter of the dense program carry their phase's scope in their HLO
     ``op_name``."""
     spec = _SUM_SPEC if combine == "sum" else traversal._SSSP_SPEC
-    sg = partition_1d(_scopes_graph(), 1)
+    # edge shards without run offsets: the scatter path
+    sg = dataclasses.replace(partition_1d(_scopes_graph(), 1),
+                             in_offsets=None)
     init = jnp.ones((V_SCOPES,), jnp.float32)
 
     def program(src, dst, w, state):
@@ -688,6 +693,75 @@ def test_dense_superstep_ops_carry_their_phase(combine):
         assert scatters == ["pregel.combine"]
     else:
         assert scatters == ["pregel.combine", "pregel.combine_empty"]
+
+
+@pytest.mark.parametrize("combine", ["sum", "min"])
+def test_runs_superstep_ops_carry_their_phase(combine, monkeypatch):
+    """With run offsets the dense program's kernel path (interpreted
+    here; off a TPU it would lower to the scatter) scatters nothing: its
+    gathers are the source-state gather (``pregel.gather``) and the read
+    of each run's end (``pregel.combine``), and min/max find empty
+    vertices by a compare under ``pregel.combine_empty``."""
+    monkeypatch.setattr(pregel, "segment_runs", functools.partial(
+        segment_runs_pallas, interpret=True))
+    monkeypatch.setattr(pregel, "_JIT_CACHE", collections.OrderedDict())
+    spec = _SUM_SPEC if combine == "sum" else traversal._SSSP_SPEC
+    sg = partition_1d(_scopes_graph(), 1)
+    assert sg.in_offsets is not None
+    init = jnp.ones((V_SCOPES,), jnp.float32)
+
+    def program(src, dst, w, state):
+        edges = dataclasses.replace(sg, src=src, dst=dst, w=w)
+        return pregel.run_pregel(spec, edges, state, 4)
+
+    ops = _scoped_ops(program, sg.src, sg.dst, sg.w, init,
+                      kinds=("gather", "scatter", "compare", "select"))
+    assert not [s for k, s in ops if k == "scatter"]
+    gathers = {s for k, s in ops if k == "gather"}
+    assert {"pregel.gather", "pregel.combine"} <= gathers
+    empty = any(s == "pregel.combine_empty" for _, s in ops)
+    assert empty == (combine == "min")
+
+
+class _Events:
+    def __init__(self):
+        self.events = []
+
+    def record_event(self, kind, attrs):
+        self.events.append((kind, attrs))
+
+    def combines(self):
+        return [a["combine"] for k, a in self.events
+                if k == "pregel.dispatch"]
+
+
+def test_dispatch_events_name_the_combine():
+    """``pregel.dispatch`` says which combine ran: ``runs`` for a
+    LocalEngine PageRank job (its shards carry run offsets),
+    ``scatter`` for a hand-built ShardedCOO without them and on the
+    mesh path."""
+    from repro.launch.mesh import make_mesh
+    g = _scopes_graph()
+    init = jnp.ones((V_SCOPES,), jnp.float32)
+    rec = _Events()
+    obs.install_observer(rec)
+    try:
+        LocalEngine(g).run("pagerank", {"max_iters": 2})
+        runs = rec.combines()
+        sg = partition_1d(g, 1)
+        hand = pregel.ShardedCOO(sg.src, sg.dst, sg.w, sg.n_vertices,
+                                 sg.n_edges, sg.n_data, sg.n_model,
+                                 sg.e_shard, sg.v_local)
+        pregel.run_pregel(_SUM_SPEC, hand, init, 2)
+        mesh = make_mesh((1,), ("data",))
+        pregel.run_pregel(_SUM_SPEC, partition_1d(g, 1, mesh=mesh), init,
+                          2, mesh=mesh)
+        # offsets on the shards do not take the mesh path off the scatter
+        pregel.run_pregel(_SUM_SPEC, sg, init, 2, mesh=mesh)
+    finally:
+        obs.uninstall_observer(rec)
+    assert runs == ["runs"]
+    assert rec.combines()[1:] == ["scatter"] * 3
 
 
 @pytest.mark.parametrize("variant", ["fused", "frontier"])

@@ -2,17 +2,34 @@
 via subprocess (XLA device-count flags must precede jax init, so the
 multi-device cases run in their own interpreter).
 """
+import collections
+import dataclasses
+import functools
 import subprocess
 import sys
 import textwrap
+import zlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import graph as G
+from repro.core import obs, pregel
+from repro.core.algorithms.connected_components import connected_components
+from repro.core.algorithms.pagerank import (_normalize_and_partition,
+                                            pagerank)
+from repro.core.algorithms.traversal import _BFS_SPEC, bfs_distances
+from repro.core.algorithms.triangles import (triangle_count,
+                                             triangle_count_reference)
 from repro.core.partition import partition_1d, partition_2d
-from repro.core.pregel import PregelSpec, run_pregel
+from repro.core.pregel import PregelSpec, batched_spec, run_pregel
 from repro.data import synthetic as S
+from repro.kernels.segment_runs import (segment_runs, segment_runs_pallas,
+                                       segment_runs_ref)
+from repro.kernels.segment_runs.kernel import LANES
+from repro.kernels.segment_runs.ops import BLOCK_ROWS, READ_CHUNK
 
 
 def test_partition_1d_conserves_edges():
@@ -37,6 +54,260 @@ def test_partition_2d_dst_ranges():
             real = row[row < 200]
             if real.size:
                 assert (real // v_local == m).all()
+
+
+def test_partition_1d_in_offsets_bound_each_vertex_run():
+    """One shard of build_coo's dst-sorted slots: vertex v's in-edges are
+    exactly slots in_offsets[v]:in_offsets[v + 1], the padding after."""
+    src, dst = S.user_follow_graph(200, 4.0, seed=0)
+    g = G.build_coo(src, dst, 200)
+    sg = partition_1d(g, 1)
+    off = np.asarray(sg.in_offsets)
+    d = np.asarray(sg.dst)
+    assert off.shape == (201,) and off.dtype == np.int32
+    assert off[0] == 0 and off[-1] == g.n_edges
+    for v in range(200):
+        assert (d[off[v]:off[v + 1]] == v).all()
+    assert (d[off[-1]:] == 200).all()
+
+
+@pytest.mark.parametrize("layout", ["unsorted", "multi_shard"])
+def test_partition_1d_leaves_in_offsets_none(layout):
+    """Slots out of destination order, or split over several shards,
+    get no offsets (the dense combine keeps its scatter)."""
+    src, dst = S.user_follow_graph(200, 4.0, seed=0)
+    g = G.build_coo(src, dst, 200)
+    if layout == "unsorted":
+        order = np.random.default_rng(0).permutation(g.n_edges)
+        g = G.GraphCOO(g.src[:g.n_edges][order], g.dst[:g.n_edges][order],
+                       g.w[:g.n_edges][order], 200, g.n_edges)
+        assert partition_1d(g, 1).in_offsets is None
+    else:
+        assert partition_1d(g, 4).in_offsets is None
+        assert partition_2d(g, 2, 2).in_offsets is None
+
+
+def _sorted_slots(rng, n_vertices, mean_degree, pad, hub=0):
+    """Dst-sorted slots with empty vertices, an optional hub and
+    ``pad`` sentinel slots (``dst = n_vertices``) at the tail."""
+    deg = rng.poisson(mean_degree, n_vertices)
+    deg[rng.random(n_vertices) < 0.3] = 0
+    if hub:
+        deg[n_vertices // 3] = hub
+    dst = np.concatenate([np.repeat(np.arange(n_vertices), deg),
+                          np.full(pad, n_vertices)]).astype(np.int32)
+    off = np.searchsorted(dst, np.arange(n_vertices + 1)).astype(np.int32)
+    return dst, off
+
+
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("case", ["empty_and_padding", "long_run",
+                                  "many_vertices", "bfloat16",
+                                  "int32", "uint32", "tiny"])
+def test_runs_combine_matches_segment_ops(op, case):
+    """The runs combine's kernel path (interpreted here) against
+    ``jax.ops.segment_*``: min, max and integer sums bit for bit; float
+    sums, whose order differs, within 8 epsilons of each vertex's sum of
+    |message| (both orders stay within one of the exact sum).
+    ``long_run`` holds a run longer than one kernel tile and a last tile
+    that overhangs the slots; ``many_vertices`` more vertices than one
+    chunk of the end read; ``bfloat16`` sums in float32 and rounds once,
+    so it is held to the float32 scatter's sum rounded to bfloat16."""
+    rng = np.random.default_rng(zlib.crc32(f"{op}-{case}".encode()))
+    n = 300
+    if case == "long_run":
+        n = 2000
+        dst, off = _sorted_slots(rng, n, 2.0, 300,
+                                 hub=BLOCK_ROWS * LANES + 3000)
+    elif case == "many_vertices":
+        n = READ_CHUNK + 1000
+        dst, off = _sorted_slots(rng, n, 1.0, 100)
+    elif case == "tiny":
+        n = 5
+        dst, off = _sorted_slots(rng, n, 1.0, 3)
+    else:
+        dst, off = _sorted_slots(rng, n, 12.0, 256 - 7)
+    if case == "int32":
+        msgs = rng.integers(-10**6, 10**6, dst.size).astype(np.int32)
+    elif case == "uint32":
+        msgs = rng.integers(0, 2**32, dst.size, dtype=np.uint32)
+    else:
+        msgs = rng.random(dst.size) * 2 - 1
+    msgs = jnp.asarray(msgs, jnp.bfloat16 if case == "bfloat16"
+                       else msgs.dtype if msgs.dtype != np.float64
+                       else jnp.float32)
+    args = (msgs, jnp.asarray(dst), jnp.asarray(off), op)
+    got = segment_runs_pallas(*args, interpret=True)
+    want = segment_runs_ref(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    if op == "sum" and case not in ("int32", "uint32"):
+        wide = (msgs.astype(jnp.float32),) + args[1:]
+        exact = np.asarray(segment_runs_ref(*wide), np.float64)
+        mass = np.asarray(segment_runs_ref(jnp.abs(wide[0]), *args[1:]))
+        eps = np.finfo(np.float32).eps
+        if case == "bfloat16":
+            want = exact.astype(jnp.bfloat16)
+            eps = float(jnp.finfo(jnp.bfloat16).eps)
+        tol = 8 * eps * mass
+        diff = np.abs(np.asarray(got, np.float64)
+                      - np.asarray(want, np.float64))
+        np.testing.assert_array_less(diff, tol + 1e-30)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_runs_combine_long_run_overhangs_last_tile():
+    """A whole-row slot count whose rows are not a multiple of the tile:
+    the last tile overhangs the array and the hub's run crosses tiles."""
+    rng = np.random.default_rng(3)
+    n = 700
+    dst, off = _sorted_slots(rng, n, 3.0, 0, hub=BLOCK_ROWS * LANES + 700)
+    extra = (-dst.size) % LANES + 5 * LANES
+    dst = np.concatenate([dst, np.full(extra, n, np.int32)])
+    assert dst.size % LANES == 0 and (dst.size // LANES) % BLOCK_ROWS
+    msgs = rng.random(dst.size).astype(np.float32)
+    got = segment_runs_pallas(jnp.asarray(msgs), jnp.asarray(dst),
+                              jnp.asarray(off), "sum", interpret=True)
+    want = np.bincount(dst[:off[-1]], weights=msgs[:off[-1]].astype(
+        np.float64), minlength=n)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5)
+
+
+def test_segment_runs_is_the_scatter_off_the_tpu():
+    """Off a TPU ``segment_runs`` lowers to the scatter it replaces, so
+    the CPU backend's dense supersteps cost what they did."""
+    rng = np.random.default_rng(5)
+    dst, off = _sorted_slots(rng, 300, 6.0, 20)
+    args = (jnp.asarray(rng.random(dst.size).astype(np.float32)),
+            jnp.asarray(dst), jnp.asarray(off))
+    hlo = jax.jit(segment_runs, static_argnums=3).lower(
+        *args, "sum").as_text()
+    assert "scatter" in hlo and "tpu_custom_call" not in hlo
+    np.testing.assert_array_equal(np.asarray(segment_runs(*args, "sum")),
+                                  np.asarray(segment_runs_ref(*args, "sum")))
+
+
+class _Combines:
+    """Observer that keeps the ``combine`` tag of each dense dispatch."""
+
+    def __init__(self):
+        self.tags = []
+
+    def record_event(self, kind, attrs):
+        if kind == "pregel.dispatch":
+            self.tags.append(attrs["combine"])
+
+
+def _combine_tags(job):
+    rec = _Combines()
+    obs.install_observer(rec)
+    try:
+        out = job()
+    finally:
+        obs.uninstall_observer(rec)
+    return out, rec.tags
+
+
+@pytest.mark.parametrize("op", ["min", "max"])
+def test_runs_combine_grouped_op_matches_scatter(op):
+    """A grouped (sum, min|max) message is not one scalar a slot, so
+    shards with run offsets keep the scatter for it, with the same
+    result as shards without."""
+    src, dst = S.user_follow_graph(300, 6.0, seed=4)
+    g = G.build_coo(src, dst, 300)
+    sg = partition_1d(g, 1)
+    ident = 0.0 if op == "min" else 1.0
+    spec = PregelSpec(
+        message=lambda s, w: jnp.concatenate([s * w[:, None], s], axis=1),
+        combine=(("sum", 2), (op, 2)),
+        apply=lambda old, agg, ids, gval: 0.5 * old + agg[:, :2]
+        + agg[:, 2:],
+        identity=(0.0, ident))
+    init = jnp.asarray(np.random.default_rng(1).random((300, 2)),
+                       jnp.float32)
+    (got, _), tags = _combine_tags(lambda: run_pregel(spec, sg, init, 3))
+    want, _ = run_pregel(spec, _without_offsets(sg), init, 3)
+    assert tags == ["scatter"]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.fixture
+def interpreted_runs_kernel(monkeypatch):
+    """Route the dense runs combine through the interpreted Pallas kernel
+    (off a TPU it would lower to the scatter), with a jit cache of its
+    own so no program built for the scatter is reused."""
+    monkeypatch.setattr(pregel, "segment_runs", functools.partial(
+        segment_runs_pallas, interpret=True))
+    monkeypatch.setattr(pregel, "_JIT_CACHE", collections.OrderedDict())
+
+
+def _without_offsets(sg):
+    assert sg.in_offsets is not None
+    return dataclasses.replace(sg, in_offsets=None)
+
+
+@pytest.mark.parametrize("algorithm", ["pagerank", "wcc", "bfs",
+                                       "batched_bfs"])
+def test_run_pregel_runs_combine_matches_scatter(algorithm,
+                                                 interpreted_runs_kernel):
+    """The same job on the same edge shards, with and without
+    ``in_offsets``, the runs side through the kernel: integer results
+    equal, PageRank to float32 rounding, and the same superstep count.
+    Batched BFS sends ``[E, B]`` messages and keeps the scatter."""
+    src, dst = S.user_follow_graph(400, 5.0, seed=11)
+    g = G.build_coo(src, dst, 400, symmetrize=algorithm == "wcc")
+    if algorithm == "pagerank":
+        sg, dangling = _normalize_and_partition(g, 1, 1)
+
+        def job(sg):
+            return pagerank(g, max_iters=30, tol=1e-9, sharded=sg,
+                            dangling=dangling)
+    elif algorithm == "wcc":
+        sg = partition_1d(g, 1)
+
+        def job(sg):
+            return connected_components(g, sharded=sg)
+    elif algorithm == "bfs":
+        sg = partition_1d(g, 1)
+
+        def job(sg):
+            return bfs_distances(g, [0, 17], sharded=sg)
+    else:
+        sg = partition_1d(g, 1)
+        init = np.full((400, 4), np.inf, np.float32)
+        for k, s0 in enumerate([0, 5, 99, 311]):
+            init[s0, k] = 0.0
+
+        def job(sg):
+            return run_pregel(batched_spec(_BFS_SPEC), sg,
+                              jnp.asarray(init), 400)
+    (got, it_runs), tags = _combine_tags(lambda: job(sg))
+    want, it_scatter = job(_without_offsets(sg))
+    assert set(tags) == {"scatter" if algorithm == "batched_bfs"
+                         else "runs"}
+    assert int(it_runs) == int(it_scatter)
+    if algorithm == "pagerank":
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-7)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_triangle_bitset_keeps_the_scatter():
+    """The bitset triangle count's ``[E, V/32 + 1]`` rows keep the
+    scatter on shards with run offsets (its width would scale the runs
+    combine's copies), and its integer-sum count message takes the runs
+    combine: the count is the numpy oracle's."""
+    src, dst = S.user_follow_graph(150, 6.0, seed=9)
+    keep = src != dst
+    g = G.build_coo(src[keep], dst[keep], 150, symmetrize=True)
+    sg = partition_1d(g, 1)
+    assert sg.in_offsets is not None
+    (count, _), tags = _combine_tags(
+        lambda: triangle_count(g, sharded=sg))
+    assert tags == ["scatter", "runs"]
+    assert count == triangle_count_reference(g.src[:g.n_edges],
+                                             g.dst[:g.n_edges], 150)
 
 
 def test_pregel_degree_count():
